@@ -24,7 +24,7 @@ __all__ = [
     "LLAMA_1B3", "LLAMA_3B", "LLAMA_7B",
     "linear_cost", "attention_cost", "stage_cost", "e2e",
     "StageCost", "E2EReport",
-    "backend_hw", "kernel_cost",
+    "DEVICE_HW", "backend_hw", "kernel_cost",
 ]
 
 Stage = Literal["prefill", "decode"]
@@ -63,8 +63,9 @@ A100_OPT = HardwareSpec("a100-opt", 312.0, 312.0, 1555.0, 300.0,
                         onchip_mb=40.0, flop_util=0.35, bw_util=0.30)
 CPU_I7 = HardwareSpec("i7-12700", 1.2, 1.2, 30.0, 65.0, onchip_mb=25.0,
                       flop_util=0.55, bw_util=0.80)
-# TPU v5e-class chip (roofline constants used throughout EXPERIMENTS.md)
-TPU_V5E = HardwareSpec("tpu-v5e", 394.0, 197.0, 819.0, 170.0, onchip_mb=128.0)
+# One TPU v5e chip.  Peaks from Google Cloud's "TPU v5e" documentation:
+# 393 TOP/s int8, 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s; 128 MiB VMEM.
+TPU_V5E = HardwareSpec("tpu-v5e", 393.0, 197.0, 819.0, 170.0, onchip_mb=128.0)
 # Generic CI-runner host: what a single XLA-CPU thread pool sustains on the
 # decode-shaped GEMMs the autotuner ranks (measured ~30 GFLOP/s effective on
 # M<=8 matmuls, ~25 GB/s streaming) — coarse on purpose: kernel_cost() only
@@ -270,9 +271,21 @@ _STEP_OVERHEAD_S = 2e-6      # per grid-step / per-chunk dispatch overhead
 TRITS_PER_BYTE_F = 5.0
 
 
-def backend_hw(backend: str) -> HardwareSpec:
-    """HardwareSpec used to rank kernel candidates on a JAX backend name."""
-    return {"tpu": TPU_V5E, "gpu": A100_OPT}.get(backend, CPU_HOST)
+# JAX ``device_kind`` -> the HardwareSpec that ranks kernel candidates on it
+# (JAX reports a v5e chip as "TPU v5 lite")
+DEVICE_HW = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E, "cpu": CPU_HOST}
+
+
+def backend_hw(device_kind: str) -> HardwareSpec:
+    """HardwareSpec of a JAX ``device_kind``.  A kind with no entry is an
+    error, not a default: ranking kernels for one chip with another's peaks
+    would pick tiles silently wrong."""
+    try:
+        return DEVICE_HW[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks known for device kind {device_kind!r}; add "
+                       f"its HardwareSpec, with a source, to "
+                       f"perfmodel.DEVICE_HW") from None
 
 
 def kernel_cost(hw: HardwareSpec, op: str, impl: str, *, m: int = 1,

@@ -17,7 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply", "split_stages"]
 
@@ -81,10 +81,10 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
         return outs.reshape(x_local.shape)
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    from repro.distributed.sharding import shard_map
-    return shard_map(
-        local, mesh=mesh,
-        in_specs=(pspec, P()),
-        out_specs=P(),
-        check_vma=False,
-    )(stage_params, x)
+    # the schedule is manual SPMD: run it on Auto-typed axes of the same
+    # devices, so that autodiff's transpose of it needs no ambient mesh
+    # context even when the caller's mesh has Explicit axes
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return jax.shard_map(local, mesh=mesh, in_specs=(pspec, P()),
+                         out_specs=P(), check_vma=False)(stage_params, x)

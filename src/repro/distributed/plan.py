@@ -33,12 +33,24 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as _rules
 
-__all__ = ["Topology", "ShardingPlan"]
+__all__ = ["Topology", "ShardingPlan", "device_shortfall_hint"]
+
+
+def device_shortfall_hint(need: int) -> str:
+    """How to get `need` devices on this backend: the virtual-device flag on
+    the CPU, the host's real chip count on an accelerator."""
+    devs = jax.devices()
+    if devs[0].platform == "cpu":
+        return (f"relaunch with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={need} "
+                f"(must be set before jax initializes)")
+    return (f"this host has {len(devs)} {devs[0].device_kind} "
+            f"chip{'s' if len(devs) != 1 else ''}")
 
 
 # -------------------------------------------------------------------------
@@ -119,11 +131,14 @@ class Topology:
         if len(devs) < self.n_devices:
             raise RuntimeError(
                 f"Topology{self.shape} needs {self.n_devices} devices, have "
-                f"{len(devs)} — relaunch with XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={self.n_devices} "
-                f"(must be set before jax initializes) or shrink --tp/--dp")
+                f"{len(devs)} — {device_shortfall_hint(self.n_devices)} "
+                f"or shrink --tp/--dp")
+        # Auto axes: GSPMD propagates the plan's shardings (jax.make_mesh
+        # defaults to Explicit axes, under which e.g. the embedding gather
+        # of a vocab-sharded table is a type error)
         return jax.make_mesh(self.shape, self.axis_names,
-                             devices=devs[:self.n_devices])
+                             devices=devs[:self.n_devices],
+                             axis_types=(AxisType.Auto,) * len(self.shape))
 
     @classmethod
     def from_mesh(cls, mesh) -> "Topology":

@@ -27,24 +27,8 @@ import jax
 from jax.sharding import PartitionSpec as P
 from jax.tree_util import DictKey, SequenceKey
 
-__all__ = ["param_specs", "zero1_specs", "batch_spec", "MODEL_AXIS",
-           "shard_map"]
+__all__ = ["param_specs", "zero1_specs", "batch_spec", "MODEL_AXIS"]
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map.
-
-    jax >= 0.6 exposes ``jax.shard_map`` with the ``check_vma`` kwarg;
-    0.4.x only has ``jax.experimental.shard_map.shard_map`` where the same
-    knob is spelled ``check_rep``.  Every shard_map in this repo goes
-    through here so multi-device code runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 MODEL_AXIS = "model"
 

@@ -122,8 +122,13 @@ class AutotuneCache:
         return TileConfig(e["impl"], e.get("block_m", 0), e.get("block_n", 0),
                           e.get("block_k", 0))
 
-    def put(self, key: str, cfg: TileConfig, us: float) -> None:
+    def put(self, key: str, cfg: TileConfig, us: float,
+            failed: list[dict] = ()) -> None:
+        """Persist the winner; ``failed`` keeps every candidate that raised
+        while being timed, with its error."""
         self.entries[key] = {**asdict(cfg), "us": round(float(us), 2)}
+        if failed:
+            self.entries[key]["failed"] = list(failed)
         self.save()
 
 
@@ -214,6 +219,14 @@ def _attn_candidates(backend: str, *, hq, hkv, lq, lk, d,
         out += [TileConfig("xla_flash", block_k=c)
                 for c in sorted({min(c, lk) for c in (128, 256, 512, lk)})]
     return list(dict.fromkeys(out))
+
+
+def _hw(backend: str):
+    """The perfmodel peaks that rank candidates on `backend`, looked up by
+    the device kind JAX reports (the interpreter runs on the host CPU)."""
+    if backend in ("cpu", "interpret"):
+        return perfmodel.backend_hw("cpu")
+    return perfmodel.backend_hw(jax.devices(backend)[0].device_kind)
 
 
 def _model_cost(hw, op: str, cfg: TileConfig, dims: dict) -> float:
@@ -352,21 +365,32 @@ def tune(op: str, *, backend: str | None = None,
         cfg = TileConfig("ref")
         cache.put(key, cfg, -1.0)
         return cfg
-    hw = perfmodel.backend_hw("cpu" if backend == "interpret" else backend)
+    hw = _hw(backend)
     ranked = sorted(cands, key=lambda c: _model_cost(hw, op, c, dims))
+    tried = ranked[:max(1, budget)]
     best, best_us = ranked[0], float("inf")
-    for cfg in ranked[:max(1, budget)]:
+    failed: list[dict] = []
+    for cfg in tried:
         try:
             if op == "sparse_attn":
                 us = _time_attn(cfg, dims, iters=iters, warmup=warmup)
             else:
                 us = _time_gemm(op, cfg, dims, iters=iters, warmup=warmup)
-        except Exception:            # infeasible candidate: skip, keep tuning
+        except Exception as e:     # infeasible candidate: record it, go on
+            failed.append({**asdict(cfg),
+                           "error": f"{type(e).__name__}: {e}"[:500]})
             continue
         cache.timed_runs += 1
         if us < best_us:
             best, best_us = cfg, us
-    cache.put(key, best, best_us if best_us < float("inf") else -1.0)
+    pallas_tried = sum(c.impl == "pallas" for c in tried)
+    if backend == "tpu" and pallas_tried and sum(
+            f["impl"] == "pallas" for f in failed) == pallas_tried:
+        # on the chip a compiler refusal is a defect to fix, never a quiet
+        # "pick the next impl"
+        raise RuntimeError(f"autotune {key}: every Pallas candidate failed: "
+                           + "; ".join(f["error"] for f in failed))
+    cache.put(key, best, best_us if best_us < float("inf") else -1.0, failed)
     return best
 
 
@@ -386,7 +410,7 @@ def lookup(op: str, *, backend: str | None = None,
     cands = candidates(op, backend, **dims)
     if not cands:
         return TileConfig("ref")
-    hw = perfmodel.backend_hw("cpu" if backend == "interpret" else backend)
+    hw = _hw(backend)
     return min(cands, key=lambda c: _model_cost(hw, op, c, dims))
 
 

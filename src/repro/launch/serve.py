@@ -21,13 +21,15 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced as reduced_cfg
-from repro.distributed.plan import Topology
+from repro.distributed.plan import Topology, device_shortfall_hint
 from repro.kernels.ops import KernelMode
 from repro.models import model as MD
 from repro.models.transformer import Runtime
@@ -37,7 +39,8 @@ from repro.serve import Request, ServeConfig, ServeEngine
 # two can never drift (satellite of the Topology/ShardingPlan redesign)
 _D = {f.name: f.default for f in dataclasses.fields(ServeConfig)}
 
-__all__ = ["make_prefill_step", "make_decode_step", "build_engine", "main"]
+__all__ = ["make_prefill_step", "make_decode_step", "build_engine",
+           "enable_compile_cache", "main"]
 
 
 def make_prefill_step(cfg, rt: Runtime, *, max_len: int):
@@ -63,9 +66,27 @@ def build_engine(cfg, rt: Runtime, config: ServeConfig | None = None,
     sparams = MD.export_serving(params, cfg)
     nbytes = sum(x.nbytes for x in jax.tree.leaves(sparams))
     mbytes = sum(x.nbytes for x in jax.tree.leaves(params))
+    del params      # the master copy is most of the device memory; free it
+                    # before the engine allocates its caches
     print(f"[serve] {cfg.name}: serving weights {nbytes/1e6:.1f} MB "
           f"(master {mbytes/1e6:.1f} MB, {mbytes/max(nbytes,1):.1f}x TWD+quant)")
     return ServeEngine(cfg, sparams, rt, config=config, **legacy)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself) and nothing else is set here.  Otherwise the cache lives at a
+    fixed directory of the checkout, ``<repo>/.jax_cache``: the directory is
+    part of the cache key, so a path that moved between runs would never
+    hit.  Called by the entry points, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _make_prompt(cfg, rng, length: int):
@@ -210,15 +231,15 @@ def _check_topology(ap, cfg, args) -> Topology | None:
     n_dev = len(jax.devices())
     if topo.n_devices > n_dev:
         ap.error(f"topology (dp={dp}, tp={tp}) needs {topo.n_devices} "
-                 f"devices but jax sees {n_dev}; relaunch with XLA_FLAGS="
-                 f"--xla_force_host_platform_device_count={topo.n_devices} "
-                 f"(set before jax initializes)")
+                 f"devices but jax sees {n_dev}; "
+                 f"{device_shortfall_hint(topo.n_devices)}")
     return topo
 
 
 def main(argv=None):
     ap = _build_parser()
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.scheduler is None:
         args.scheduler = "deadline" if args.serve_http else "fifo"
     if args.preemption and args.scheduler != "deadline":

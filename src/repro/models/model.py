@@ -140,19 +140,29 @@ def decode_step(p: dict, cfg: ModelConfig, caches: dict, token_or_embed,
     return _logits(p, cfg, x)[:, 0], caches
 
 
+def _per_group(f, stacked: jax.Array):
+    """vmap `f` over the leading group axis one group at a time: the export
+    intermediates (f32/int32 copies of a weight) then exist for one layer at
+    a time, not for the whole stack."""
+    outs = [jax.vmap(f)(stacked[g:g + 1]) for g in range(stacked.shape[0])]
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs), *outs)
+
+
 def export_serving(p: dict, cfg: ModelConfig) -> dict:
     """Master weights -> serving representation (TWD packing, Sec. III-E).
 
-    Scan-stacked leaves (leading group axis) are exported per-group via vmap
-    so per-tensor scales stay per-layer."""
+    Scan-stacked leaves (leading group axis) are exported per group, so
+    per-tensor scales stay per-layer and the export's working memory is one
+    layer's, not the stack's."""
     def conv(tree: Any) -> Any:
         if isinstance(tree, dict):
             if "w" in tree and hasattr(tree["w"], "ndim"):
                 if tree["w"].ndim == 2:
                     return export_tlin(tree, cfg.ternary)
                 if tree["w"].ndim == 3:      # stacked (G, K, N)
-                    return jax.vmap(lambda w: export_tlin({"w": w},
-                                                          cfg.ternary))(tree["w"])
+                    return _per_group(lambda w: export_tlin({"w": w},
+                                                            cfg.ternary),
+                                      tree["w"])
             if "experts_gate" in tree:
                 if tree["experts_gate"]["w"].ndim == 4:  # stacked (G,E,D,F)
                     return jax.vmap(lambda t: MOE.export_moe(t, cfg))(tree)

@@ -25,7 +25,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig, MoeConfig
 from repro.core import ternary as tq
 from repro.core import twd
-from repro.distributed.sharding import shard_map
 from repro.models.ternary_linear import tlin_apply, tlin_compact
 
 __all__ = ["moe_init", "moe_apply", "export_moe", "decode_capacity"]
@@ -224,7 +223,7 @@ def moe_apply(p: dict, cfg: ModelConfig, x: jax.Array, *, mesh=None,
                                   ei * e_local, e_local, cap)
             return jax.lax.psum(y, ep_axis).reshape(x_blk.shape)
 
-        y = shard_map(
+        y = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(dp_axes, None, None), specs, P()),
             out_specs=P(dp_axes, None, None),

@@ -133,18 +133,22 @@ def stack_init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
               if cfg.shared_attn and any(k in ("attn", "local") for k in kinds)
               else None)
 
-    def one(i):
-        return block_init(keys[i], cfg, kinds[i], dtype,
-                          shared_attn=cfg.shared_attn and kinds[i] in ("attn", "local"))
+    def one(key, kind):
+        shared_attn = cfg.shared_attn and kind in ("attn", "local")
+        return block_init(key, cfg, kind, dtype, shared_attn=shared_attn)
 
     stacked = None
     if n_groups:
-        per_pos = []
-        for j in range(plen):
-            trees = [one(g * plen + j) for g in range(n_groups)]
-            per_pos.append(jax.tree.map(lambda *xs: jnp.stack(xs), *trees))
-        stacked = tuple(per_pos)
-    tail_params = tuple(one(n_groups * plen + i) for i in range(tail))
+        # one batched init per pattern position, straight into the stacked
+        # layout: stacking per-layer trees would hold every weight twice
+        # (a 3B master in bf16 is 6.6 GB of a 16 GB chip)
+        stacked = tuple(
+            jax.vmap(lambda k, kind=kind: one(k, kind))(
+                keys[j:n_groups * plen:plen])
+            for j, kind in enumerate(pat))
+    first = n_groups * plen
+    tail_params = tuple(one(keys[i], kinds[i])
+                        for i in range(first, first + tail))
     return {"stacked": stacked, "tail": tail_params, "shared": shared}
 
 

@@ -66,6 +66,5 @@ def compressed_crosspod_mean(grads: Any, error: Any, mesh,
                 treedef.unflatten([o[1] for o in out]))
 
     spec = jax.tree.map(lambda _: P(), grads)
-    from repro.distributed.sharding import shard_map
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec), check_vma=False)(grads, error)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec), check_vma=False)(grads, error)

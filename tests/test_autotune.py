@@ -141,6 +141,51 @@ def test_candidates_feasibility():
     assert cfg.impl == "ref"
 
 
+def test_failed_candidates_are_recorded(cache, monkeypatch):
+    """A candidate that raises while timed is kept, with its error, in the
+    cache entry; tuning goes on with the others."""
+    real = autotune._time_gemm
+
+    def flaky(op, cfg, dims, **kw):
+        if cfg.impl == "xla_dense_plain":
+            raise ValueError("refused by the compiler")
+        return real(op, cfg, dims, **kw)
+
+    monkeypatch.setattr(autotune, "_time_gemm", flaky)
+    dims = dict(m=2, k=320, n=128, keep=16, block=32)
+    won = autotune.tune("das_ternary_gemm", backend="cpu", cache=cache,
+                        budget=10, iters=1, **dims)
+    entry = cache.entries[autotune.shape_key("das_ternary_gemm", "cpu",
+                                             **dims)]
+    assert won.impl != "xla_dense_plain"
+    assert [f["impl"] for f in entry["failed"]] == ["xla_dense_plain"]
+    assert "refused by the compiler" in entry["failed"][0]["error"]
+
+
+def test_tpu_raises_when_every_pallas_candidate_fails(cache, monkeypatch):
+    """On a TPU backend a Pallas refusal is a defect, not a cue to serve
+    through the next implementation."""
+    def refuse_pallas(op, cfg, dims, **kw):
+        if cfg.impl == "pallas":
+            raise RuntimeError("Mosaic: unsupported shape cast")
+        return 1.0
+
+    monkeypatch.setattr(autotune, "_time_gemm", refuse_pallas)
+    monkeypatch.setattr(autotune, "_hw", lambda backend: CPU_HOST)
+    with pytest.raises(RuntimeError, match="every Pallas candidate failed"):
+        autotune.tune("das_ternary_gemm", backend="tpu", cache=cache,
+                      budget=100, iters=1, m=4, k=640, n=256, keep=16,
+                      block=32)
+
+
+def test_backend_hw_is_keyed_by_device_kind():
+    from repro.core import perfmodel
+    assert perfmodel.backend_hw("TPU v5 lite") is perfmodel.TPU_V5E
+    assert perfmodel.backend_hw("cpu") is CPU_HOST
+    with pytest.raises(KeyError, match="no peaks known"):
+        perfmodel.backend_hw("TPU v99")
+
+
 # -------------------------------------------------------------------------
 # (c) compiled / tuned / interpret / ref parity
 # -------------------------------------------------------------------------

@@ -53,7 +53,8 @@ def test_resharding_restore(tmp_path):
     from jax.sharding import PartitionSpec as P
     t = {"w": jnp.arange(32.0).reshape(8, 4)}
     save_checkpoint(str(tmp_path), 1, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     got, _ = restore_checkpoint(str(tmp_path), mesh=mesh,
                                 specs={"w": P("data", None)})
     np.testing.assert_array_equal(np.asarray(got["w"]), np.asarray(t["w"]))

@@ -94,3 +94,27 @@ def test_sparse_attention_ring_positions(rng):
     b = np.asarray(ops.sparse_attention(q, k, v, qp, kp, sink=8, window=56,
                                         mode="ref"))
     np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gemm_kernels_partial_tiles(rng, dtype):
+    """M and N that the tiles do not divide (the grid's last blocks are
+    partial), several K steps, and several N tiles reusing one M tile's
+    scattered activations: both kernels still match their oracles."""
+    m, k, n, keep = 12, 960, 200, 16
+    tiles = dict(block_m=8, block_n=128, block_k=1)
+    trits = rng.integers(-1, 2, size=(k, n)).astype(np.int8)
+    packed = jnp.asarray(twd.pack_ternary(trits))
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.dtype(dtype))
+    tol = 2e-2 if dtype == "bfloat16" else 1e-4
+    y = ops.ternary_gemm(x, packed, 0.5, mode="interpret", **tiles)
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(ref.ternary_gemm_packed_ref(x, packed, 0.5,
+                                                               k)),
+        rtol=tol, atol=tol)
+    ca = das.das_compact(x, block_size=32, keep=keep)
+    y = ops.das_ternary_gemm(ca.values, ca.indices, packed, 0.5, keep=keep,
+                             mode="interpret", **tiles)
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(ref.das_ternary_gemm_ref(
+            ca.values, ca.indices, packed, 0.5, k)), rtol=tol, atol=tol)

@@ -20,7 +20,8 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
                                + " --xla_force_host_platform_device_count=8")
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+AUTO = AxisType.Auto
 
 from repro.configs import get_config, reduced
 from repro.models import model as MD
@@ -36,7 +37,7 @@ if jax.device_count() != 8:
 # ---- 1. sharded train step == single device ------------------------------
 cfg = reduced(get_config("bitnet-1.3b"))
 cfg = dataclasses.replace(cfg, ternary=dataclasses.replace(cfg.ternary, das=None))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AUTO,) * 2)
 p = MD.init_params(jax.random.PRNGKey(0), cfg)
 toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab)
 batch = {"inputs": toks, "labels": jnp.roll(toks, -1, 1)}
@@ -77,7 +78,8 @@ print("OK moe-ep")
 
 # ---- 3. pipeline parallel == plain ---------------------------------------
 from repro.distributed.pipeline import pipeline_apply
-mesh_pp = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_pp = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                        axis_types=(AUTO,) * 3)
 W = jax.random.normal(jax.random.PRNGKey(3), (2, 16, 16)) * 0.3  # 2 stages
 def stage_fn(w, xb):
     return jnp.tanh(xb @ w)
@@ -124,6 +126,9 @@ print("ALL-MULTIDEVICE-OK")
 def test_multidevice_semantics():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # virtual CPU devices only: the child must never reach for an
+    # accelerator, which the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,

@@ -121,3 +121,31 @@ def test_legacy_kwargs_warn_and_match_config(sparams):
 def test_unknown_engine_kwarg_is_typeerror(sparams):
     with pytest.raises(TypeError, match="unknown ServeEngine kwarg"):
         ServeEngine(CFG, sparams, Runtime(), max_slotz=1)
+
+
+# -------------------------------------------------------------------------
+# persistent compilation cache of the entry points
+# -------------------------------------------------------------------------
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, monkeypatch, from_env):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to the fixed <repo>/.jax_cache on every call."""
+    import pathlib
+
+    from repro.launch.serve import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            repo = pathlib.Path(__file__).resolve().parents[1]
+            want = str(repo / ".jax_cache")
+            assert enable_compile_cache() == want
+            assert enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -150,11 +150,15 @@ def test_engine_eos_frees_slot(sparams):
     eng = ServeEngine(CFG, sparams, Runtime(), max_slots=1, max_len=64)
     eng.submit(Request(uid=0, prompt=prompt, max_new_tokens=50))
     free_run = eng.run()[0]
-    eos = int(free_run.tokens[2])     # pretend the 3rd sampled id is EOS
+    # pretend the first sampled id that did not occur earlier in the output
+    # is EOS: generation must stop exactly there
+    toks = free_run.tokens.tolist()
+    stop = next(i for i, t in enumerate(toks) if i > 0 and t not in toks[:i])
+    eos = toks[stop]
     eng2 = ServeEngine(CFG, sparams, Runtime(), max_slots=1, max_len=64)
     eng2.submit(Request(uid=0, prompt=prompt, max_new_tokens=50, eos_id=eos))
     stopped = eng2.run()[0]
-    assert len(stopped.tokens) == 3 and stopped.tokens[-1] == eos
+    assert stopped.tokens.tolist() == toks[:stop + 1]
 
 
 def test_engine_rejects_bad_requests_and_resets(sparams):

@@ -150,6 +150,9 @@ print("ALL-SHARDED-OK")
 def test_sharded_serving_8dev_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # virtual CPU devices only: the child must never reach for an
+    # accelerator, which the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
