@@ -56,6 +56,7 @@ def _check(k: int, block_size: int, keep: int) -> None:
         raise ValueError(f"keep={keep} out of range for block {block_size}")
 
 
+@jax.named_scope("das")
 def das_mask(x: jax.Array, *, block_size: int = DEFAULT_BLOCK,
              keep: int | None = None, sparsity: float = 0.5) -> jax.Array:
     """Top-K-per-block bitmask over |x| along the last axis (the paper's ASM).
@@ -92,6 +93,7 @@ def das_mask(x: jax.Array, *, block_size: int = DEFAULT_BLOCK,
     return mask.reshape(x.shape)
 
 
+@jax.named_scope("das")
 def das_apply(x: jax.Array, mask: jax.Array) -> jax.Array:
     """Masked activations.  Gradient flows through surviving lanes only
     (mask treated as constant — the paper's sparsify-then-quantize QAT)."""
@@ -99,6 +101,7 @@ def das_apply(x: jax.Array, mask: jax.Array) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("block_size", "keep"))
+@jax.named_scope("das")
 def das_compact(x: jax.Array, *, block_size: int = DEFAULT_BLOCK,
                 keep: int = DEFAULT_BLOCK // 2) -> CompactActivation:
     """Compact the Top-K lanes of every block (the butterfly-router output).

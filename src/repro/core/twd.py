@@ -96,6 +96,7 @@ def pack_ternary(values: jax.Array | np.ndarray,
     return packed.astype(jnp.uint8)
 
 
+@jax.named_scope("twd_unpack")
 def unpack_ternary(packed: jax.Array, k: int) -> jax.Array:
     """Decode uint8 base-3 bytes back to int8 trits along axis 0.
 
@@ -110,6 +111,7 @@ def unpack_ternary(packed: jax.Array, k: int) -> jax.Array:
     return flat[:k].astype(jnp.int8)
 
 
+@jax.named_scope("twd_unpack")
 def unpack_ternary_arith(packed: jax.Array, k: int) -> jax.Array:
     """Arithmetic (gather-free) decode: repeated div/mod by 3.
 

@@ -112,5 +112,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        # kept free of "sparse_attention" (see kernels/das_gemm.py's name)
+        name="sparse_attn",
     )(q, k, v, q_pos.astype(jnp.int32)[:, None],
       k_pos.astype(jnp.int32)[None, :])

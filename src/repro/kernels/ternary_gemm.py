@@ -65,6 +65,7 @@ def twd_decode(packed: jax.Array, *, block_n: int = 256,
         out_specs=pl.BlockSpec((bkp * TRITS_PER_BYTE, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((kp * TRITS_PER_BYTE, n), jnp.int8),
         interpret=interpret,
+        name="twd_decode",
     )(packed)
 
 
@@ -189,4 +190,5 @@ def ternary_gemm(x: jax.Array, packed: jax.Array, w_scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ternary_gemm",
     )(slab_major(x, K_SLAB), packed, w_scale, x_scale)

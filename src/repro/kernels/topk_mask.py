@@ -56,4 +56,5 @@ def topk_mask(x: jax.Array, *, keep: int = BLOCK // 2, block: int = BLOCK,
         out_specs=pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.int8),
         interpret=interpret,
+        name="topk_mask",
     )(x)

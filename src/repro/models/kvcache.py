@@ -132,6 +132,7 @@ def is_paged(cache: dict) -> bool:
 # attention cache write / read
 # --------------------------------------------------------------------------
 
+@jax.named_scope("kv_write")
 def attn_write(cache: dict, k_new: jax.Array, v_new: jax.Array, t: jax.Array,
                *, sink: int, window: int, ring: bool,
                page_table: jax.Array | None = None) -> dict:
@@ -207,6 +208,7 @@ def attn_read(cache: dict, page_table: jax.Array | None = None):
     return cache["k"], cache["v"], cache["pos"]
 
 
+@jax.named_scope("kv_write")
 def ring_from_stream(cfg: ModelConfig, state, *, sink: int, window: int) -> dict:
     """Convert a core.lpsa.lpsa_prefill scan carry into a decode ring cache.
 

@@ -92,9 +92,11 @@ def prefill(p: dict, cfg: ModelConfig, batch_in: jax.Array,
     """Serving prefill: -> (last-position logits (B, V), caches)."""
     s = batch_in.shape[1]
     max_len = max_len if max_len is not None else s + 1
-    x = _inputs_to_x(p, cfg, batch_in)
+    with jax.named_scope("embed"):
+        x = _inputs_to_x(p, cfg, batch_in)
     x, caches = T.stack_prefill(p["layers"], cfg, x, rt, max_len)
-    return _logits(p, cfg, x[:, -1:])[:, 0], caches
+    with jax.named_scope("lm_head"):
+        return _logits(p, cfg, x[:, -1:])[:, 0], caches
 
 
 def init_caches(p_or_none, cfg: ModelConfig, batch: int, max_len: int,
@@ -135,9 +137,11 @@ def decode_step(p: dict, cfg: ModelConfig, caches: dict, token_or_embed,
     the shared arenas.  -> (logits (B, V), new caches)."""
     if token_or_embed.ndim == 1:
         token_or_embed = token_or_embed[:, None]
-    x = _inputs_to_x(p, cfg, token_or_embed)
+    with jax.named_scope("embed"):
+        x = _inputs_to_x(p, cfg, token_or_embed)
     x, caches = T.stack_decode(p["layers"], cfg, x, caches, t, rt, page_table)
-    return _logits(p, cfg, x)[:, 0], caches
+    with jax.named_scope("lm_head"):
+        return _logits(p, cfg, x)[:, 0], caches
 
 
 def _per_group(f, stacked: jax.Array):

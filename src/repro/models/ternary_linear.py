@@ -147,6 +147,7 @@ def _apply_packed_tuned(p: dict, x2: jax.Array, tc: TernaryConfig,
         scale, keep=tc.das.keep, block=tc.das.block, cfg=cfg)
 
 
+@jax.named_scope("twd_unpack")
 def _unpack5(packed: jax.Array) -> jax.Array:
     """Slice-free base-3 decode: (Kp, N) u8 -> (5*Kp, N) i8 trits.
 

@@ -199,32 +199,12 @@ def block_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
                   shared, rt: Runtime, batch: int, max_len: int):
     """-> (x, cache_entry) with caches ready for decode at position L."""
     km = rt.kernel_mode
-    dt = x.dtype
     if kind in ("attn", "local"):
-        ap = _attn_params(bp, shared)
-        xin = L.rmsnorm(bp["norm1"], x)
-        sink, window = A.kind_sink_window(cfg, kind, rt.serve_sparse)
-        if sink < A.FULL_SINK:   # sparse: streaming prefill -> ring cache
-            y, state = A.attn_prefill_streaming(ap, cfg, xin, kind,
-                                                kernel_mode=km)
-            cache = KV.ring_from_stream(cfg, state, sink=sink, window=window)
-        else:                    # full attention -> full cache
-            q, k, v = A.qkv_project(ap, cfg, xin, kernel_mode=km)
-            pos = jnp.arange(x.shape[1])
-            rp = A._rope_fn(cfg)
-            q, k = rp(q, pos), rp(k, pos)
-            o = A.flash_masked(q, k, v, pos, pos, sink=A.FULL_SINK, window=0,
-                               softcap=cfg.attn_softcap)
-            y = tlin_apply(ap["wo"], o.reshape(x.shape[0], x.shape[1], -1),
-                           cfg.ternary, kernel_mode=km)
-            full = KV.init_cache(cfg, KV.CacheSpec("full", batch,
-                                                   max_len=max_len, dtype=dt))
-            kpad = full["k"].at[:, :k.shape[1]].set(k.astype(dt))
-            vpad = full["v"].at[:, :v.shape[1]].set(v.astype(dt))
-            ppad = full["pos"].at[:, :k.shape[1]].set(pos.astype(jnp.int32))
-            cache = {"k": kpad, "v": vpad, "pos": ppad}
-        x = x + y
-        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
+        with jax.named_scope("attn"):
+            x, cache = _attn_prefill(bp, cfg, x, kind, shared, rt, batch,
+                                     max_len)
+        with jax.named_scope("ffn"):
+            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
         return x, cache
     if kind == "mamba":
         y, state = M.mamba_train(
@@ -237,9 +217,42 @@ def block_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
         y, s_fin = G.gla_train(bp["gla"], cfg, L.rmsnorm(bp["norm1"], x),
                                kernel_mode=km)
         x = x + y
-        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
+        with jax.named_scope("ffn"):
+            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
         return x, {"s": s_fin}
     raise ValueError(kind)
+
+
+def _attn_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
+                  shared, rt: Runtime, batch: int, max_len: int):
+    """The attention half of a block's prefill: -> (x + attention, the
+    layer's decode cache)."""
+    km = rt.kernel_mode
+    dt = x.dtype
+    ap = _attn_params(bp, shared)
+    xin = L.rmsnorm(bp["norm1"], x)
+    sink, window = A.kind_sink_window(cfg, kind, rt.serve_sparse)
+    if sink < A.FULL_SINK:   # sparse: streaming prefill -> ring cache
+        y, state = A.attn_prefill_streaming(ap, cfg, xin, kind,
+                                            kernel_mode=km)
+        cache = KV.ring_from_stream(cfg, state, sink=sink, window=window)
+    else:                    # full attention -> full cache
+        q, k, v = A.qkv_project(ap, cfg, xin, kernel_mode=km)
+        pos = jnp.arange(x.shape[1])
+        rp = A._rope_fn(cfg)
+        q, k = rp(q, pos), rp(k, pos)
+        o = A.flash_masked(q, k, v, pos, pos, sink=A.FULL_SINK, window=0,
+                           softcap=cfg.attn_softcap)
+        y = tlin_apply(ap["wo"], o.reshape(x.shape[0], x.shape[1], -1),
+                       cfg.ternary, kernel_mode=km)
+        full = KV.init_cache(cfg, KV.CacheSpec("full", batch,
+                                               max_len=max_len, dtype=dt))
+        with jax.named_scope("kv_write"):
+            kpad = full["k"].at[:, :k.shape[1]].set(k.astype(dt))
+            vpad = full["v"].at[:, :v.shape[1]].set(v.astype(dt))
+            ppad = full["pos"].at[:, :k.shape[1]].set(pos.astype(jnp.int32))
+        cache = {"k": kpad, "v": vpad, "pos": ppad}
+    return x + y, cache
 
 
 def block_decode(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
@@ -251,13 +264,15 @@ def block_decode(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
     layout)."""
     km = rt.kernel_mode
     if kind in ("attn", "local"):
-        y, cache = A.attn_decode(_attn_params(bp, shared), cfg,
-                                 L.rmsnorm(bp["norm1"], x), cache, t, kind,
-                                 serve_sparse=rt.serve_sparse, kernel_mode=km,
-                                 page_table=page_table)
-        x = x + y
-        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
-                           decode=True)
+        with jax.named_scope("attn"):
+            y, cache = A.attn_decode(_attn_params(bp, shared), cfg,
+                                     L.rmsnorm(bp["norm1"], x), cache, t,
+                                     kind, serve_sparse=rt.serve_sparse,
+                                     kernel_mode=km, page_table=page_table)
+            x = x + y
+        with jax.named_scope("ffn"):
+            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
+                               decode=True)
         return x, cache
     if kind == "mamba":
         y, cache = M.mamba_decode(bp["mamba"], cfg,
@@ -278,8 +293,9 @@ def block_decode(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
         y, cache = G.gla_decode(bp["gla"], cfg, L.rmsnorm(bp["norm1"], x),
                                 cache, kernel_mode=km)
         x = x + y
-        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
-                           decode=True)
+        with jax.named_scope("ffn"):
+            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
+                               decode=True)
         return x, cache
     raise ValueError(kind)
 

@@ -49,6 +49,19 @@ prefix instead of re-prefilling it.  Shared pages are copy-on-write: the
 first divergent write to a page with refcount > 1 copies it; retiring a
 slot releases its references and scrubs pages that drop free.  Used pool
 memory therefore tracks live tokens, not ``max_slots * max_len``.
+
+Tracing: the engine writes host spans into the JAX profiler's trace (on
+the device trace's clock; next to free while no trace is being taken).
+Each decode tick is ``serve.tick`` (a step marker, ``step_num``) with
+children ``serve.tick.prepare`` (host arrays, page table, transfers),
+``.dispatch`` (the jitted step's call), ``.wait`` (the token sync) and
+``.deliver`` (per-slot delivery, ``on_token``, retirements).  Each
+admission is ``serve.admit`` (``uid``, ``prompt_len``, ``packs``) with
+``serve.admit.prefill``, ``.insert``, ``.sample`` and ``.wait``; then
+``serve.poll`` (the ``run_forever`` poll hook), ``serve.retire`` (``uid``)
+and ``serve.recover``.  On the device the executables are ``jit__step_fn``,
+``jit_prefill``, ``jit_sample_first`` and the cache helpers, and the model
+code carries named scopes (README "Telemetry").
 """
 
 from __future__ import annotations
@@ -61,6 +74,7 @@ from dataclasses import dataclass, field, replace
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -95,6 +109,11 @@ class RequestResult:
                                    # (admitted in an earlier tick)
     slo_steps: int | None = None   # deadline budget the request carried
     preempted: bool = False        # truncated by the deadline-rescue hook
+    # wall-clock stamps (time.perf_counter seconds) at submit, admission
+    # and first token: the instants the serve.admit / serve.tick spans mark
+    submit_time: float | None = None
+    admit_time: float | None = None
+    first_token_time: float | None = None
 
     @property
     def latency_steps(self) -> int:
@@ -107,6 +126,20 @@ class RequestResult:
     @property
     def queue_wait_steps(self) -> int:
         return self.admit_vtime - self.arrival
+
+    @property
+    def queue_wait_s(self) -> float | None:
+        """Wall seconds from submit to admission (None when unstamped)."""
+        if self.submit_time is None or self.admit_time is None:
+            return None
+        return self.admit_time - self.submit_time
+
+    @property
+    def ttft_s(self) -> float | None:
+        """Wall seconds from submit to the first token."""
+        if self.submit_time is None or self.first_token_time is None:
+            return None
+        return self.first_token_time - self.submit_time
 
     @property
     def slo_met(self) -> bool:
@@ -156,7 +189,8 @@ class EngineStats:
 class _Slot:
     __slots__ = ("state", "req", "input_tok", "input_x", "input_pos",
                  "tail", "tail_idx", "out", "admit_vtime", "first_tok_vtime",
-                 "admitted_with_active", "pages", "page_budget")
+                 "admit_t", "first_tok_t", "admitted_with_active", "pages",
+                 "page_budget")
 
     def __init__(self):
         self.state = FREE
@@ -289,6 +323,7 @@ class ServeEngine:
         self._slots = [_Slot() for _ in range(max_slots)]
         self._results: dict[int, RequestResult] = {}
         self._pending_uids: set[int] = set()
+        self._submit_t: dict[int, float] = {}   # uid -> perf_counter at submit
         self._base_key = jax.random.PRNGKey(config.seed)
         self._sampler = make_sampler(config.top_k)
         self._top_k = config.top_k
@@ -361,8 +396,17 @@ class ServeEngine:
                        "out_shardings": (rep, csh)}
             cache_kw = {"out_shardings": csh}
 
-        self._prefill = jax.jit(
-            lambda sp, x: MD.prefill(sp, cfg, x, rt, max_len=max_len))
+        # named functions: the executables compile as jit_prefill and
+        # jit_sample_first, the names a trace shows for them
+        def prefill(sp, x):
+            return MD.prefill(sp, cfg, x, rt, max_len=max_len)
+
+        def sample_first(lg, uid, ctr, temp):
+            with jax.named_scope("sample"):
+                return sample_token(lg, self._fold_key(uid, ctr), temp,
+                                    self._top_k)
+
+        self._prefill = jax.jit(prefill)
         self._step = jax.jit(self._step_fn, donate_argnums=(1,), **step_kw)
         self._insert = jax.jit(self._insert_fn, donate_argnums=(0,),
                                **cache_kw)
@@ -375,9 +419,7 @@ class ServeEngine:
         self._scrub = jax.jit(self._scrub_fn, donate_argnums=(0,), **cache_kw)
         self._scrub_slot = jax.jit(self._scrub_slot_fn, donate_argnums=(0,),
                                    **cache_kw)
-        self._sample1 = jax.jit(
-            lambda lg, uid, ctr, temp: sample_token(
-                lg, self._fold_key(uid, ctr), temp, self._top_k))
+        self._sample1 = jax.jit(sample_first)
 
     # -- layer-layout structure helpers -----------------------------------
 
@@ -497,9 +539,10 @@ class ServeEngine:
         else:
             logits, caches = MD.decode_step(sparams, self.cfg, caches, tok, t,
                                             self.rt, pt)
-        keys = jax.vmap(self._fold_key)(uids, counters)
-        next_tok = self._sampler(logits, keys, temps)
-        next_tok = jnp.where(active, next_tok, 0)
+        with jax.named_scope("sample"):
+            keys = jax.vmap(self._fold_key)(uids, counters)
+            next_tok = self._sampler(logits, keys, temps)
+            next_tok = jnp.where(active, next_tok, 0)
         return next_tok, caches
 
     def _insert_fn(self, big, small, slot):
@@ -712,6 +755,7 @@ class ServeEngine:
                     f"request uid {req.uid} has an unclaimed result; "
                     f"pop_result/drain_results it before resubmitting")
             self._pending_uids.add(req.uid)
+            self._submit_t[req.uid] = time.perf_counter()
             self.scheduler.add(req)
 
     def pop_result(self, uid: int) -> RequestResult | None:
@@ -822,7 +866,8 @@ class ServeEngine:
                 if should_stop is not None and should_stop():
                     break
                 if poll is not None:
-                    poll()
+                    with TraceAnnotation("serve.poll"):
+                        poll()
                 self._admit_ready()
                 if self.num_active:
                     try:
@@ -862,16 +907,17 @@ class ServeEngine:
         dropped.  Single-device engines rebuild in place (lost capacity 0).
         """
         t0 = time.perf_counter()
-        with self._lock:
+        with TraceAnnotation("serve.recover"), self._lock:
             snaps = []
             for s in self._slots:
                 if s.state == FREE:
                     continue
                 snaps.append({
                     "req": s.req, "out": list(s.out),
-                    "admit_vtime": s.admit_vtime,
+                    "admit_vtime": s.admit_vtime, "admit_t": s.admit_t,
                     # no first token yet -> let replay stamp it on emission
                     "first_tok_vtime": s.first_tok_vtime if s.out else None,
+                    "first_tok_t": s.first_tok_t if s.out else None,
                     "admitted_with_active": s.admitted_with_active})
                 s.state = FREE
                 s.req = None
@@ -976,24 +1022,29 @@ class ServeEngine:
         slot = self._slots[idx]
         p = req.prompt_len
         prefix = (p // self._chunk) * self._chunk
-        self._pending_uids.discard(req.uid)
-        # mid-decode admission metric: slots already mid-stream (admitted in
-        # an EARLIER tick) — same-tick co-admissions don't count
-        slot.admitted_with_active = sum(
-            1 for s2 in self._slots
-            if s2.state != FREE and s2.admit_vtime < self.vtime)
-        slot.req = req
-        slot.admit_vtime = self.vtime
-        slot.out = []
-        slot.input_x = None
-        slot.first_tok_vtime = None
-        if self._paged:
-            ok = self._admit_paged(idx, slot, req, prefix)
-            if not ok:
-                slot.req = None     # back off: slot stays FREE
-            return ok
-        self._admit_dense(idx, slot, req, prefix)
-        return True
+        with TraceAnnotation("serve.admit", uid=req.uid, prompt_len=p,
+                             packs=prefix // self._chunk):
+            self._pending_uids.discard(req.uid)
+            # mid-decode admission metric: slots already mid-stream
+            # (admitted in an EARLIER tick) — same-tick co-admissions don't
+            # count
+            slot.admitted_with_active = sum(
+                1 for s2 in self._slots
+                if s2.state != FREE and s2.admit_vtime < self.vtime)
+            slot.req = req
+            slot.admit_vtime = self.vtime
+            slot.admit_t = time.perf_counter()
+            slot.out = []
+            slot.input_x = None
+            slot.first_tok_vtime = None
+            slot.first_tok_t = None
+            if self._paged:
+                ok = self._admit_paged(idx, slot, req, prefix)
+                if not ok:
+                    slot.req = None     # back off: slot stays FREE
+                return ok
+            self._admit_dense(idx, slot, req, prefix)
+            return True
 
     def _replay_admit(self, idx: int, snap: dict) -> bool:
         """Re-admit a snapshot taken by `recover()`: prefill the original
@@ -1003,39 +1054,52 @@ class ServeEngine:
         slot = self._slots[idx]
         req = snap["req"]
         prefix = (req.prompt_len // self._chunk) * self._chunk
-        slot.admitted_with_active = snap["admitted_with_active"]
-        slot.req = req
-        slot.admit_vtime = snap["admit_vtime"]
-        slot.out = list(snap["out"])
-        slot.input_x = None
-        slot.first_tok_vtime = None
-        replay = tuple(snap["out"])
-        if self._paged:
-            if not self._admit_paged(idx, slot, req, prefix,
-                                     replay=replay, notify=False):
-                slot.req = None     # back off: slot stays FREE
-                return False
-        else:
-            self._admit_dense(idx, slot, req, prefix,
-                              replay=replay, notify=False)
-        if snap["first_tok_vtime"] is not None:
-            slot.first_tok_vtime = snap["first_tok_vtime"]
-        return True
+        with TraceAnnotation("serve.admit", uid=req.uid,
+                             prompt_len=req.prompt_len,
+                             packs=prefix // self._chunk):
+            slot.admitted_with_active = snap["admitted_with_active"]
+            slot.req = req
+            slot.admit_vtime = snap["admit_vtime"]
+            slot.admit_t = snap["admit_t"]
+            slot.out = list(snap["out"])
+            slot.input_x = None
+            slot.first_tok_vtime = None
+            slot.first_tok_t = None
+            replay = tuple(snap["out"])
+            if self._paged:
+                if not self._admit_paged(idx, slot, req, prefix,
+                                         replay=replay, notify=False):
+                    slot.req = None     # back off: slot stays FREE
+                    return False
+            else:
+                self._admit_dense(idx, slot, req, prefix,
+                                  replay=replay, notify=False)
+            if snap["first_tok_vtime"] is not None:
+                slot.first_tok_vtime = snap["first_tok_vtime"]
+                slot.first_tok_t = snap["first_tok_t"]
+            return True
 
     def _admit_dense(self, idx: int, slot: _Slot, req: Request,
                      prefix: int, replay: tuple = (),
                      notify: bool = True) -> None:
-        p = req.prompt_len
         if prefix > 0:
-            logits, small = self._prefill(self.sparams,
-                                          jnp.asarray(req.prompt)[None, :prefix])
-            self.stats.prefill_tokens += prefix
+            logits, small = self._prefill_prompt(req, prefix)
         else:
             logits, small = None, self._empty1
-        self.caches = self._insert(self.caches, small, jnp.int32(idx))
+        with TraceAnnotation("serve.admit.insert", uid=req.uid):
+            self.caches = self._insert(self.caches, small, jnp.int32(idx))
         self._start_slot(idx, slot, req, prefix,
                          logits[0] if logits is not None else None,
                          replay=replay, notify=notify)
+
+    def _prefill_prompt(self, req: Request, prefix: int):
+        """Dispatch the batch-1 packed prefill of the prompt's first
+        ``prefix`` tokens -> (logits (1, V), batch-1 caches)."""
+        with TraceAnnotation("serve.admit.prefill", uid=req.uid):
+            out = self._prefill(self.sparams,
+                                jnp.asarray(req.prompt)[None, :prefix])
+        self.stats.prefill_tokens += prefix
+        return out
 
     def _feed(self, slot: _Slot, nxt) -> None:
         """Route one tail element into the decode step's input: raw
@@ -1063,12 +1127,16 @@ class ServeEngine:
         if notify and self.telemetry is not None:
             self.telemetry.on_admit(req, self.vtime)
         if absorbed == p and not replay:
-            tok = int(self._sample1(jnp.asarray(logits), jnp.int32(req.uid),
+            with TraceAnnotation("serve.admit.sample", uid=req.uid):
+                tok = self._sample1(jnp.asarray(logits), jnp.int32(req.uid),
                                     jnp.int32(len(slot.out)),
-                                    jnp.float32(req.temperature)))
+                                    jnp.float32(req.temperature))
+            with TraceAnnotation("serve.admit.wait", uid=req.uid):
+                tok = int(tok)
             slot.state = DECODE
             if slot.first_tok_vtime is None:
                 slot.first_tok_vtime = self.vtime
+                slot.first_tok_t = time.perf_counter()
             slot.out.append(tok)
             slot.input_tok = tok
             slot.input_pos = p
@@ -1156,25 +1224,25 @@ class ServeEngine:
             self.stats.prefix_hits += 1
             self.stats.prompt_tokens_reused += shared_len
             if kind == "exact" and entry.state is not None:
-                self.caches = self._insert_shared(self.caches, entry.state,
-                                                  jnp.int32(idx))
+                with TraceAnnotation("serve.admit.insert", uid=req.uid):
+                    self.caches = self._insert_shared(
+                        self.caches, entry.state, jnp.int32(idx))
             logits = entry.logits if (kind == "exact" and shared_len == p) \
                 else None
             absorbed = shared_len
         else:
             if prefix > 0:
-                lg, small = self._prefill(
-                    self.sparams, jnp.asarray(req.prompt)[None, :prefix])
-                self.stats.prefill_tokens += prefix
+                lg, small = self._prefill_prompt(req, prefix)
             else:
                 lg, small = None, self._empty1
             fresh = [self._alloc_page() for _ in range(immediate)]
             pages[:len(fresh)] = fresh
             page_vec = np.zeros(max(n_seq, 1), np.int32)
             page_vec[:len(fresh)] = fresh
-            self.caches = self._insert_paged(self.caches, small,
-                                            jnp.int32(idx),
-                                            jnp.asarray(page_vec))
+            with TraceAnnotation("serve.admit.insert", uid=req.uid):
+                self.caches = self._insert_paged(self.caches, small,
+                                                jnp.int32(idx),
+                                                jnp.asarray(page_vec))
             if register:
                 ent = PrefixEntry(length=prefix, pages=tuple(fresh),
                                   state=self._snapshot_rest(small),
@@ -1269,11 +1337,35 @@ class ServeEngine:
     # -- the decode tick --------------------------------------------------
 
     def step_decode(self) -> None:
-        tick_t0 = time.perf_counter()
-        if self.fault_injector is not None:
-            # simulated device/host loss lands here, mid-serving; the run
-            # loops catch WorkerFailure and call recover()
-            self.fault_injector.maybe_fail(self.stats.decode_steps)
+        step = self.stats.decode_steps
+        with StepTraceAnnotation("serve.tick", step_num=step):
+            tick_t0 = time.perf_counter()
+            if self.fault_injector is not None:
+                # simulated device/host loss lands here, mid-serving; the
+                # run loops catch WorkerFailure and call recover()
+                self.fault_injector.maybe_fail(step)
+            with TraceAnnotation("serve.tick.prepare", step_num=step):
+                n_active, args = self._tick_inputs()
+            with TraceAnnotation("serve.tick.dispatch", step_num=step):
+                next_tok, self.caches = self._step(self.sparams, self.caches,
+                                                   *args)
+            with TraceAnnotation("serve.tick.wait", step_num=step):
+                next_tok = np.asarray(next_tok)
+
+            self.stats.decode_steps += 1
+            self.stats.active_slot_steps += n_active
+            self.vtime += 1
+            with TraceAnnotation("serve.tick.deliver", step_num=step):
+                self._tick_deliver(next_tok)
+
+            if self.telemetry is not None:
+                self.telemetry.on_tick(self, n_active,
+                                       time.perf_counter() - tick_t0)
+
+    def _tick_inputs(self) -> tuple[int, tuple]:
+        """The decode step's per-slot inputs, built on the host and
+        transferred: (active slot count, the step's arguments after the
+        params and caches)."""
         b = self.max_slots
         tok = np.zeros((b,), np.int32)
         # paged: inactive rows carry t = -1 so their writes land on the null
@@ -1304,16 +1396,16 @@ class ServeEngine:
             self._ensure_writable_pages()
 
         pt = jnp.asarray(self._pt) if self._paged else None
-        next_tok, self.caches = self._step(
-            self.sparams, self.caches, pt, jnp.asarray(tok), jnp.asarray(t),
-            jnp.asarray(temps), jnp.asarray(uids), jnp.asarray(counters),
-            jnp.asarray(active), jnp.asarray(forced), jnp.asarray(forced_x))
-        next_tok = np.asarray(next_tok)
+        return int(active.sum()), (
+            pt, jnp.asarray(tok), jnp.asarray(t), jnp.asarray(temps),
+            jnp.asarray(uids), jnp.asarray(counters), jnp.asarray(active),
+            jnp.asarray(forced), jnp.asarray(forced_x))
 
-        self.stats.decode_steps += 1
-        self.stats.active_slot_steps += int(active.sum())
-        self.vtime += 1
-
+    def _tick_deliver(self, next_tok: np.ndarray) -> None:
+        """Advance every slot by the tick's tokens: feed the next prompt
+        tail element, or deliver the sampled token (retiring finished
+        slots)."""
+        now = time.perf_counter()
         for i, s in enumerate(self._slots):
             if s.state == PREFILL:
                 if s.tail_idx < len(s.tail):
@@ -1327,13 +1419,10 @@ class ServeEngine:
                     s.input_x = None
                     if s.first_tok_vtime is None:
                         s.first_tok_vtime = self.vtime
+                        s.first_tok_t = now
                     self._deliver(i, int(next_tok[i]))
             elif s.state == DECODE:
                 self._deliver(i, int(next_tok[i]))
-
-        if self.telemetry is not None:
-            self.telemetry.on_tick(self, int(active.sum()),
-                                   time.perf_counter() - tick_t0)
 
     def _deliver(self, idx: int, tok: int) -> None:
         s = self._slots[idx]
@@ -1353,31 +1442,35 @@ class ServeEngine:
     def _retire(self, idx: int, preempted: bool = False) -> None:
         s = self._slots[idx]
         r = s.req
-        result = RequestResult(
-            uid=r.uid, tokens=np.asarray(s.out, np.int32),
-            prompt_len=r.prompt_len, arrival=r.arrival,
-            admit_vtime=s.admit_vtime, first_token_vtime=s.first_tok_vtime,
-            finish_vtime=self.vtime,
-            admitted_with_active=s.admitted_with_active,
-            slo_steps=r.slo_steps, preempted=preempted)
-        self._results[r.uid] = result
-        if self._paged and s.pages is not None:
-            held = [pg for pg in s.pages if pg]
-            if held:
-                self._scrub_pages(self._pool.release(held))
-            self._pt[idx, :] = 0
-            s.pages = None
-            s.page_budget = 0
-        self.caches = self._scrub_slot(self.caches, self._empty1,
-                                       jnp.int32(idx))
-        s.state = FREE
-        s.req = None
-        s.input_x = None
-        s.tail = None
-        if self.telemetry is not None:
-            self.telemetry.on_finish(result, self)
-        if self.on_finish is not None:
-            self.on_finish(result)
+        with TraceAnnotation("serve.retire", uid=r.uid):
+            result = RequestResult(
+                uid=r.uid, tokens=np.asarray(s.out, np.int32),
+                prompt_len=r.prompt_len, arrival=r.arrival,
+                admit_vtime=s.admit_vtime,
+                first_token_vtime=s.first_tok_vtime,
+                finish_vtime=self.vtime,
+                admitted_with_active=s.admitted_with_active,
+                slo_steps=r.slo_steps, preempted=preempted,
+                submit_time=self._submit_t.pop(r.uid, None),
+                admit_time=s.admit_t, first_token_time=s.first_tok_t)
+            self._results[r.uid] = result
+            if self._paged and s.pages is not None:
+                held = [pg for pg in s.pages if pg]
+                if held:
+                    self._scrub_pages(self._pool.release(held))
+                self._pt[idx, :] = 0
+                s.pages = None
+                s.page_budget = 0
+            self.caches = self._scrub_slot(self.caches, self._empty1,
+                                           jnp.int32(idx))
+            s.state = FREE
+            s.req = None
+            s.input_x = None
+            s.tail = None
+            if self.telemetry is not None:
+                self.telemetry.on_finish(result, self)
+            if self.on_finish is not None:
+                self.on_finish(result)
 
     # -- pool introspection ------------------------------------------------
 

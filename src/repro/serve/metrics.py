@@ -13,10 +13,11 @@
 From those it maintains (a) cumulative counters that must agree with
 ``EngineStats`` (tokens, requests, preemptions — test-asserted), (b) a
 rolling window of recent ticks/requests for live gauges (tok/s over wall
-time, slot utilization, TTFT/latency/queue-wait percentiles, SLO
-attainment), and (c) an optional JSON-lines export: one ``{"type":
-"request", ...}`` line per finished request plus a ``{"type": "tick",
-...}`` snapshot line every ``snapshot_every`` ticks — the flight recorder
+time, slot utilization, TTFT/latency/queue-wait percentiles in virtual
+steps, TTFT and queue wait in wall seconds, SLO attainment), and (c) an
+optional JSON-lines export: one ``{"type": "request", ...}`` line per
+finished request plus a ``{"type": "tick", ...}`` snapshot line every
+``snapshot_every`` ticks — the flight recorder
 a long-running server leaves behind.  ``snapshot()`` returns the live
 gauge dict the HTTP ``/metrics`` endpoint serves.
 
@@ -46,6 +47,10 @@ def _pct(values, q) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+def _stamped(records, key) -> list:
+    return [r[key] for r in records if r[key] is not None]
+
+
 class Telemetry:
     def __init__(self, engine=None, jsonl_path: str | None = None,
                  window: int = 256, snapshot_every: int = 64):
@@ -60,7 +65,6 @@ class Telemetry:
         # cumulative counters (must track EngineStats)
         self.tokens_out = 0
         self.requests_finished = 0
-        self.prefill_tokens = 0
         self.queue_wait_steps = 0
         self.slo_tracked = 0
         self.slo_met = 0
@@ -116,6 +120,10 @@ class Telemetry:
             "queue_wait_steps": result.queue_wait_steps,
             "ttft_steps": result.ttft_steps,
             "latency_steps": result.latency_steps,
+            # wall clock (the engine's perf_counter stamps at submit,
+            # admission and first token); None where a stamp is missing
+            "queue_wait_s": result.queue_wait_s,
+            "ttft_s": result.ttft_s,
             "slo_steps": result.slo_steps,
             "slo_met": result.slo_met,
             "preempted": result.preempted,
@@ -185,6 +193,12 @@ class Telemetry:
                     [r["latency_steps"] for r in recent], 95),
                 "queue_wait_steps_p50": _pct(
                     [r["queue_wait_steps"] for r in recent], 50),
+                "ttft_s_p50": _pct(_stamped(recent, "ttft_s"), 50),
+                "ttft_s_p95": _pct(_stamped(recent, "ttft_s"), 95),
+                "queue_wait_s_p50": _pct(_stamped(recent, "queue_wait_s"),
+                                         50),
+                "queue_wait_s_p95": _pct(_stamped(recent, "queue_wait_s"),
+                                         95),
             },
             "totals": totals,
             "slo_attainment": (totals["slo_met"] / totals["slo_tracked"]

@@ -76,6 +76,12 @@ def test_telemetry_matches_engine_stats(sparams, tmp_path):
     assert snap["engine"]["kernel_fallbacks"] == eng.kernel_fallback_deltas()
     assert snap["pool"]["layout"] in ("paged", "dense")
     assert 0.0 < snap["rolling"]["slot_utilization"] <= 1.0
+    # wall-clock gauges from the engine's submit / admit / first-token stamps
+    roll = snap["rolling"]
+    assert 0.0 <= roll["queue_wait_s_p50"] <= roll["queue_wait_s_p95"]
+    assert 0.0 < roll["ttft_s_p50"] <= roll["ttft_s_p95"]
+    assert roll["queue_wait_s_p95"] <= roll["ttft_s_p95"]
+    assert not hasattr(tele, "prefill_tokens")    # the dead counter is gone
 
     tele.close()
     lines = [json.loads(x) for x in path.read_text().splitlines()]
@@ -85,6 +91,29 @@ def test_telemetry_matches_engine_stats(sparams, tmp_path):
     assert ticks, "expected periodic tick snapshots"
     assert sum(x["new_tokens"] for x in reqs) == eng.stats.generated_tokens
     assert all(x["slo_met"] for x in reqs if x["slo_steps"] is not None)
+    by_uid = {x["uid"]: x for x in reqs}
+    for uid, r in res.items():
+        assert by_uid[uid]["queue_wait_s"] == r.queue_wait_s >= 0.0
+        assert by_uid[uid]["ttft_s"] == r.ttft_s > r.queue_wait_s
+
+
+def test_request_results_carry_wall_clock_stamps(sparams):
+    """submit <= admission <= first token <= retirement, on the engine's
+    perf_counter; a request with a prompt tail gets its first token from a
+    decode tick, one whose prompt is whole packs at admission."""
+    import time
+    eng = _engine(sparams)
+    t0 = time.perf_counter()
+    eng.submit(_req(0, plen=8))     # one whole pack (chunk 8)
+    eng.submit(_req(1, plen=11))    # one pack and a 3-token tail
+    res = eng.run()
+    t1 = time.perf_counter()
+    for r in res.values():
+        assert t0 <= r.submit_time <= r.admit_time <= r.first_token_time <= t1
+        assert r.queue_wait_s == r.admit_time - r.submit_time
+        assert r.ttft_s == r.first_token_time - r.submit_time
+    assert res[0].first_token_vtime == res[0].admit_vtime
+    assert res[1].first_token_vtime > res[1].admit_vtime
 
 
 def test_kernel_fallback_deltas_are_per_engine(sparams):
