@@ -8,10 +8,12 @@ skips the matching weight channels, shrinking the effective GEMM K-dim by S_a.
 
      Y = (Q_int8(X) .* M) @ Q_1.58(W)^T ,   M = TopK_block(|X|)      (Eq. 1)
 
-TPU realization: the mask is computed by a vectorized per-block top-k; the
-"butterfly" becomes a block-structured gather that *compacts* both the
-activations and the ternary weight rows to dense (S_a*K)-long tiles before the
-MXU matmul (kernels/das_gemm.py).  This module holds the pure-JAX semantics:
+TPU realization: the mask is a per-block rank comparison (no sort); the
+"butterfly" becomes a per-block one-hot select that *compacts* the kept
+activations and their lane ids to dense (S_a*K)-long rows, which the fused
+kernel scatters back against the ternary weight slab before the MXU matmul
+(kernels/das_gemm.py).  No top-k, sort or gather: all elementwise compares
+and small reductions, which fuse.  This module holds the pure-JAX semantics:
 
   * ``das_mask``      — the N:M bitmask (ASM in the paper),
   * ``das_apply``     — masked activations (training / QAT path),
@@ -78,19 +80,26 @@ def das_mask(x: jax.Array, *, block_size: int = DEFAULT_BLOCK,
         return jnp.concatenate([main, tail], axis=-1)
     nb = k // block_size
     xb = jnp.abs(x).reshape(x.shape[:-1] + (nb, block_size))
-    # Rank-comparison form (no sort): lane survives iff
-    #   #{|x_j| > |x_i|} + #{j < i : |x_j| == |x_i|} < keep.
-    # O(B^2)=32x32 compares — pure elementwise/reduce ops, which GSPMD
-    # partitions cleanly (lax.top_k lowers to sort, which XLA SPMD
-    # *fully replicates*: a 22 GiB all-gather per mask at pod scale).
-    ai = xb[..., :, None]
-    aj = xb[..., None, :]
+    return _block_keep(xb, keep).reshape(x.shape)
+
+
+def _block_keep(ab: jax.Array, keep: int) -> jax.Array:
+    """Kept lanes of each block of ``ab`` = |x| of shape (..., nb, B).
+
+    Rank-comparison form (no sort): lane survives iff
+      #{|x_j| > |x_i|} + #{j < i : |x_j| == |x_i|} < keep,
+    so ties go to the lower lane, as ``lax.top_k`` breaks them.
+    O(B^2)=32x32 compares — pure elementwise/reduce ops, which GSPMD
+    partitions cleanly (lax.top_k lowers to sort, which XLA SPMD
+    *fully replicates*: a 22 GiB all-gather per mask at pod scale).
+    """
+    ai = ab[..., :, None]
+    aj = ab[..., None, :]
     gt = jnp.sum((aj > ai), axis=-1)
-    lane = jnp.arange(block_size)
+    lane = jnp.arange(ab.shape[-1])
     jlt = (lane[None, :] < lane[:, None])
     eq_before = jnp.sum((aj == ai) & jlt, axis=-1)
-    mask = (gt + eq_before) < keep
-    return mask.reshape(x.shape)
+    return (gt + eq_before) < keep
 
 
 @jax.named_scope("das")
@@ -106,21 +115,34 @@ def das_compact(x: jax.Array, *, block_size: int = DEFAULT_BLOCK,
                 keep: int = DEFAULT_BLOCK // 2) -> CompactActivation:
     """Compact the Top-K lanes of every block (the butterfly-router output).
 
-    Returns values (..., nb*keep) and absolute lane indices; indices within a
-    block are ascending, so the downstream weight gather is quasi-contiguous.
+    Returns values (..., nb*keep) in ``x.dtype`` and absolute int32 lane
+    indices; indices within a block are ascending, so the downstream weight
+    gather is quasi-contiguous.  The kept lanes are `das_mask`'s; a kept
+    lane's slot is the count of kept lanes before it in its block, and each
+    slot takes its lane by a one-hot select over the block — no top-k, sort
+    or gather.  The select picks the value's bits, not a float sum, so the
+    output is bit-exact (a summed -0.0 would come out +0.0).
     """
     k = x.shape[-1]
     _check(k, block_size, keep)
     nb = k // block_size
     xb = x.reshape(x.shape[:-1] + (nb, block_size))
-    _, idx = jax.lax.top_k(jnp.abs(xb), keep)      # (..., nb, keep)
-    idx = jnp.sort(idx, axis=-1)
-    vals = jnp.take_along_axis(xb, idx, axis=-1)   # (..., nb, keep)
+    kept = _block_keep(jnp.abs(xb), keep)                   # (..., nb, B)
+    lane = jnp.arange(block_size, dtype=jnp.int32)
+    # slot of a kept lane = kept lanes at lower index (exclusive cumsum)
+    before = lane[None, :] < lane[:, None]                  # (B_i, B_j)
+    slot = jnp.sum(kept[..., None, :] & before, axis=-1, dtype=jnp.int32)
+    slots = jnp.arange(keep, dtype=jnp.int32)[:, None]      # (keep, 1)
+    onehot = kept[..., None, :] & (slot[..., None, :] == slots)
+    uint = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    bits = jax.lax.bitcast_convert_type(xb, uint)[..., None, :]
+    vals = jax.lax.bitcast_convert_type(
+        jnp.sum(jnp.where(onehot, bits, 0), axis=-1, dtype=uint), x.dtype)
+    idx = jnp.sum(jnp.where(onehot, lane, 0), axis=-1)    # (..., nb, keep)
     base = (jnp.arange(nb, dtype=jnp.int32) * block_size)[:, None]
-    abs_idx = idx.astype(jnp.int32) + base          # absolute lane ids
     newshape = x.shape[:-1] + (nb * keep,)
     return CompactActivation(values=vals.reshape(newshape),
-                             indices=abs_idx.reshape(newshape),
+                             indices=(idx + base).reshape(newshape),
                              keep_per_block=keep, block_size=block_size)
 
 

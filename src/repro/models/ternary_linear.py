@@ -45,10 +45,10 @@ __all__ = ["tlin_init", "tlin_apply", "tlin_compact", "export_tlin",
 
 class MaskedActivation(NamedTuple):
     """Densified DAS-masked activations — the tuned-mode shared prep when the
-    autotuned impl is one of the ``xla_dense_*`` decode-GEMMs (a rank-compare
-    mask is ~5x cheaper than the top-k compaction on XLA-CPU).  Produced by
-    `tlin_compact`, consumed by `tlin_apply` via ``ca=`` like its compacted
-    sibling `core.das.CompactActivation`."""
+    autotuned impl is one of the ``xla_dense_*`` decode-GEMMs, which take the
+    dense masked input (the rank-compare mask without the compaction's
+    one-hot select).  Produced by `tlin_compact`, consumed by `tlin_apply`
+    via ``ca=`` like its compacted sibling `core.das.CompactActivation`."""
 
     x: jax.Array   # (..., K) f32, dropped lanes zeroed
 

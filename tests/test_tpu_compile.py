@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.das import das_compact
 from repro.kernels.das_gemm import das_ternary_gemm
 from repro.kernels.sparse_attn import sparse_attention
 from repro.kernels.ternary_gemm import ternary_gemm
@@ -120,3 +121,22 @@ def test_kernel_instruction_names(one_chip, kernel, name):
     assert any(f"%{name}." in i.split(" = ", 1)[1] for i in ins), \
         "nothing reads the kernel's output"
     assert not [i for i in ins if kernel in i]
+
+
+@pytest.mark.parametrize("m,k", [
+    (256, D_FF),     # one LPSA pack of prefill: down projection's input
+    (4, D_MODEL),    # decode: attention / gate-up input
+])
+def test_das_compact_has_no_gather_or_sort(one_chip, m, k):
+    """DAS compaction compiles to compares, reductions and selects: no
+    gather and no sort instruction (the op_name metadata may still name
+    its jnp calls)."""
+    arg = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda x: das_compact(x, block_size=32, keep=16)) \
+        .lower(arg).compile().as_text()
+    # an instruction reads "<shape> <opcode>(<operands>), <attributes>"
+    ops = [re.search(r"(?:^|\s)([a-z][\w-]*)\(", i.split(" = ", 1)[1])
+           for i in _instructions(text)]
+    opcodes = {o.group(1) for o in ops if o}
+    assert "fusion" in opcodes or "reduce" in opcodes
+    assert not opcodes & {"gather", "sort"}, sorted(opcodes)
