@@ -7,7 +7,8 @@ from importlib import import_module
 from .base import ModelConfig, reduced  # noqa: F401
 from .shapes import SHAPES, ShapeSpec, shape_by_name  # noqa: F401
 
-# the 10 assigned architectures + the paper's own models
+# the 10 assigned architectures, the paper's own models, then the models
+# added to the benchmark
 ARCH_MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
@@ -22,9 +23,10 @@ ARCH_MODULES = {
     "bitnet-3b": "bitnet_3b",
     "bitnet-1.3b": "bitnet_1p3b",
     "gla-1.3b": "gla_1p3b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2p5b",
 }
 ASSIGNED = tuple(list(ARCH_MODULES)[:10])
-PAPER_OWN = tuple(list(ARCH_MODULES)[10:])
+PAPER_OWN = tuple(list(ARCH_MODULES)[10:13])
 
 
 def get_config(arch: str) -> ModelConfig:

@@ -14,7 +14,7 @@ from typing import Literal
 
 __all__ = [
     "DasConfig", "LpsaConfig", "TernaryConfig", "MoeConfig", "SsmConfig",
-    "ModelConfig", "reduced",
+    "YarnConfig", "ModelConfig", "reduced",
 ]
 
 Family = Literal["dense", "moe", "hybrid", "ssm", "audio", "vlm"]
@@ -75,6 +75,21 @@ class SsmConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (arXiv 2309.00071), the parameters of HF's
+    ``rope_type: "yarn"``: inverse frequencies blended between the original
+    (extrapolated) and ``factor``-interpolated ones across the correction
+    range that ``beta_fast`` / ``beta_slow`` rotations over
+    ``original_max`` positions give; cos and sin scaled by
+    ``attention_factor``."""
+    factor: float
+    original_max: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: Family
@@ -92,6 +107,9 @@ class ModelConfig:
     attn_softcap: float | None = None
     logit_softcap: float | None = None
     rope_theta: float = 10_000.0
+    # YaRN scaling of the global ("attn") layers' RoPE; "local" layers keep
+    # plain RoPE at rope_theta (Mellum 2's rope_parameters)
+    rope_yarn: YarnConfig | None = None
     tie_embeddings: bool = True
     act: str = "silu"
     ffn_kind: str = "gated"     # gated (3-mat GLU) | mlp (2-mat)
@@ -103,6 +121,10 @@ class ModelConfig:
     # TENET features
     ternary: TernaryConfig = field(default_factory=TernaryConfig)
     lpsa: LpsaConfig | None = field(default_factory=LpsaConfig)
+    # serve global ("attn") layers as LPSA sink+window (Runtime.serve_sparse);
+    # False keeps them full causal, for models whose published global layers
+    # are full beside their windowed ones (lpsa.chunk stays the pack size)
+    lpsa_on_globals: bool = True
     # numerics / runtime
     dtype: str = "bfloat16"
     remat: bool = True

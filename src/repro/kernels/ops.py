@@ -47,7 +47,8 @@ from .topk_mask import topk_mask as _topk_mask_pallas
 __all__ = [
     "KernelMode", "KERNEL_MODES",
     "backend_kind", "pallas_compiled_ok", "use_pallas",
-    "kernel_wanted", "attn_kernel_wanted", "packed_gemm_ok", "fused_das_ok",
+    "kernel_wanted", "attn_kernel_wanted", "attn_kernel_fits",
+    "packed_gemm_ok", "fused_das_ok",
     "note_fallback", "fallback_counts", "reset_fallbacks",
     "twd_decode", "ternary_gemm", "das_gemv", "das_ternary_gemm",
     "topk_mask", "sparse_attention", "K_SLAB",
@@ -135,6 +136,14 @@ def attn_kernel_wanted(mode: str) -> bool:
     ``interpret`` keeps the XLA flash path (emulated attention per decode
     step is pathological) and ``tuned`` picks per-shape in the caller."""
     return mode in ("pallas", "compiled") or (mode == "auto" and use_pallas())
+
+
+def attn_kernel_fits(hq: int, hkv: int, lk: int, block_k: int = 128) -> bool:
+    """True when the Pallas ``sparse_attn`` kernel tiles a decode over a
+    cache of `lk` positions: whole groups of query heads per kv head, and
+    key blocks the TPU's (8, 128) tiling accepts (the cache in one block,
+    or in whole blocks of 128 lanes)."""
+    return hq % hkv == 0 and (lk <= block_k or lk % 128 == 0)
 
 
 def _pallas_opts(mode: str) -> dict | None:

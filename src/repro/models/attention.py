@@ -2,7 +2,9 @@
 
 Layer kinds (configs.base.layer_pattern):
   "attn"  — global attention: full causal, or sink+window when cfg.lpsa set
-  "local" — sliding-window attention (window = cfg.window, no sink)
+            and cfg.lpsa_on_globals; RoPE with cfg.rope_yarn's scaling
+  "local" — sliding-window attention (window = cfg.window, no sink), plain
+            RoPE
 
 Three execution paths share one set of (ternary) projection weights:
   * train / full-prefill: chunked flash attention in pure JAX (differentiable,
@@ -27,6 +29,7 @@ from repro.models.ternary_linear import tlin_apply, tlin_compact, tlin_init
 __all__ = [
     "attn_init", "qkv_project", "flash_masked", "attn_train",
     "attn_prefill_streaming", "attn_decode", "kind_sink_window",
+    "attn_scope",
 ]
 
 NEG_INF = -1e30
@@ -34,12 +37,20 @@ FULL_SINK = 1 << 30   # sink larger than any position == full causal
 
 
 def kind_sink_window(cfg: ModelConfig, kind: str, serve_sparse: bool) -> tuple[int, int]:
-    """(sink, window) for a layer kind.  serve_sparse toggles LPSA on globals."""
+    """(sink, window) for a layer kind.  serve_sparse toggles LPSA on the
+    globals of a config that allows it (cfg.lpsa_on_globals)."""
     if kind == "local":
         return 0, cfg.window
-    if cfg.lpsa is not None and serve_sparse:
+    if cfg.lpsa is not None and cfg.lpsa_on_globals and serve_sparse:
         return cfg.lpsa.sink, cfg.lpsa.window
     return FULL_SINK, 0
+
+
+def attn_scope(sink: int) -> str:
+    """Named scope of a layer's kind of attention, inside ``attn``:
+    ``attn_window`` over a ring (sink+window or sliding), ``attn_full``
+    over every earlier position."""
+    return "attn_full" if sink >= FULL_SINK else "attn_window"
 
 
 def attn_init(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict:
@@ -71,9 +82,13 @@ def qkv_project(p: dict, cfg: ModelConfig, x: jax.Array, *,
             v.reshape(b, l, cfg.n_kv_heads, hd))
 
 
-def _rope_fn(cfg: ModelConfig):
+def _rope_fn(cfg: ModelConfig, kind: str):
+    """RoPE of a layer kind: global layers take cfg.rope_yarn's scaling,
+    local (sliding) layers plain RoPE."""
+    yarn = cfg.rope_yarn if kind == "attn" else None
+
     def f(x, pos):
-        cos, sin = L.rope(pos, cfg.head_dim_, cfg.rope_theta)
+        cos, sin = L.rope(pos, cfg.head_dim_, cfg.rope_theta, yarn)
         return L.apply_rope(x, cos, sin)
     return f
 
@@ -138,7 +153,7 @@ def attn_train(p: dict, cfg: ModelConfig, x: jax.Array, kind: str, *,
     sink, window = kind_sink_window(cfg, kind, serve_sparse)
     q, k, v = qkv_project(p, cfg, x, kernel_mode=kernel_mode)
     pos = jnp.arange(l)
-    rp = _rope_fn(cfg)
+    rp = _rope_fn(cfg, kind)
     q, k = rp(q, pos), rp(k, pos)
     o = flash_masked(q, k, v, pos, pos, sink=sink, window=window,
                      softcap=cfg.attn_softcap)
@@ -162,7 +177,8 @@ def attn_prefill_streaming(p: dict, cfg: ModelConfig, x: jax.Array, kind: str,
     o, state = lpsa_lib.lpsa_prefill(
         x, proj, spec=spec, num_q_heads=cfg.n_heads,
         num_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim_,
-        rope=_rope_fn(cfg), softcap=cfg.attn_softcap, return_state=True)
+        rope=_rope_fn(cfg, kind), softcap=cfg.attn_softcap,
+        return_state=True)
     b, l = x.shape[0], x.shape[1]
     y = tlin_apply(p["wo"], o.reshape(b, l, cfg.q_dim), cfg.ternary,
                    kernel_mode=kernel_mode)
@@ -197,7 +213,7 @@ def attn_decode(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     if t.ndim == 0:
         t = jnp.broadcast_to(t, (b,))
     pos = t[:, None]                                     # (B, 1)
-    rp = _rope_fn(cfg)
+    rp = _rope_fn(cfg, kind)
     q, k = rp(q, pos), rp(k, pos)
     ring = sink < FULL_SINK
     cache = kvcache.attn_write(cache, k, v, t, sink=sink, window=window,
@@ -214,18 +230,25 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, k_pos, *, sink: int,
     """Route the one-token attention step by kernel mode.
 
     q: (B, Lq, Hq, D); k, v: (B, Lk, Hkv, D); q_pos (B, Lq); k_pos (B, Lk).
-    ``pallas``/``compiled`` go through the Pallas LPSA kernel; ``tuned``
-    resolves the per-shape winner from the autotune cache — Pallas tiles
-    where they compile, the chunked XLA flash (with the tuned kv-chunk)
-    otherwise; everything else keeps `flash_masked`, which shares the
-    decode step's per-token compaction budget with the ternary linears
-    (one fused LPSA+DAS decode trace).
+    ``pallas``/``compiled`` go through the Pallas LPSA kernel where its
+    tiles fit the shape (``ops.attn_kernel_fits``: GQA heads, a cache whose
+    length the key blocks tile) and note a fallback to `flash_masked`
+    where they do not; ``tuned`` resolves the per-shape winner from the
+    autotune cache — Pallas tiles where they compile, the chunked XLA flash
+    (with the tuned kv-chunk) otherwise; everything else keeps
+    `flash_masked`, which shares the decode step's per-token compaction
+    budget with the ternary linears (one fused LPSA+DAS decode trace).
     """
     b, lq, hq, d = q.shape
     lk, hkv = k.shape[1], k.shape[2]
     kv_chunk = min(512, lk)
     tiles: dict = {}
     route_pallas = ops.attn_kernel_wanted(kernel_mode)
+    if route_pallas and not ops.attn_kernel_fits(hq, hkv, lk):
+        ops.note_fallback("sparse_attn", (hq, hkv, lk, d),
+                          f"{hq} q heads over {hkv} kv heads and a cache of "
+                          f"{lk} do not tile the kernel's key blocks")
+        route_pallas = False
     if kernel_mode == "tuned":
         from repro.kernels import autotune
         tcfg = autotune.lookup(

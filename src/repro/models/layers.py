@@ -8,11 +8,15 @@ wo/w_out = row-parallel, embed = vocab-parallel, …).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
-    "rmsnorm", "init_rmsnorm", "rope", "apply_rope", "softcap",
+    "rmsnorm", "init_rmsnorm", "rope", "yarn_inv_freq", "apply_rope",
+    "softcap",
     "dense_init", "embed_init", "take_embed", "logits_from_embed",
     "ACT",
 ]
@@ -42,12 +46,42 @@ def softcap(x: jax.Array, cap: float | None) -> jax.Array:
     return jnp.tanh(x / cap) * cap
 
 
-def rope(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """cos/sin tables for absolute positions.  positions: (...,) int."""
+def rope(positions: jax.Array, head_dim: int, theta: float,
+         yarn=None) -> tuple[jax.Array, jax.Array]:
+    """cos/sin tables for absolute positions.  positions: (...,) int.
+    ``yarn`` (a configs.base.YarnConfig) scales the frequencies and the
+    tables as YaRN does."""
     half = head_dim // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        mscale = 1.0
+    else:
+        freq = jnp.asarray(yarn_inv_freq(head_dim, theta, yarn), jnp.float32)
+        mscale = yarn.attention_factor
     ang = positions.astype(jnp.float32)[..., None] * freq  # (..., half)
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies (head_dim/2,), as HF transformers'
+    ``_compute_yarn_parameters`` with ``truncate``: index i keeps its
+    original frequency theta^(-2i/d) up to the floor of the correction
+    dimension of ``beta_fast`` rotations, takes it / ``factor`` from the
+    ceiling of that of ``beta_slow`` rotations, and is ramped linearly
+    between the two."""
+    half = head_dim // 2
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = theta ** (-np.arange(half, dtype=np.float64) / half)
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return extra * (1.0 - ramp) + extra / yarn.factor * ramp
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -130,18 +130,23 @@ def init_caches(p_or_none, cfg: ModelConfig, batch: int, max_len: int,
 
 
 def decode_step(p: dict, cfg: ModelConfig, caches: dict, token_or_embed,
-                t, rt: Runtime = Runtime(), page_table=None):
+                t, rt: Runtime = Runtime(), page_table=None, *,
+                with_stats: bool = False):
     """One decode step.  t: scalar position (lock-step batch) or (B,)
     per-sequence positions (continuous batching); for paged caches inactive
     rows pass t = -1 and ``page_table`` (B, pages_per_seq) int32 addresses
-    the shared arenas.  -> (logits (B, V), new caches)."""
+    the shared arenas.  -> (logits (B, V), new caches), and with
+    ``with_stats`` the MoE layers' routing counters (transformer
+    ``_layer_counters``; None without MoE layers)."""
     if token_or_embed.ndim == 1:
         token_or_embed = token_or_embed[:, None]
     with jax.named_scope("embed"):
         x = _inputs_to_x(p, cfg, token_or_embed)
-    x, caches = T.stack_decode(p["layers"], cfg, x, caches, t, rt, page_table)
+    x, caches, stats = T.stack_decode(p["layers"], cfg, x, caches, t, rt,
+                                      page_table)
     with jax.named_scope("lm_head"):
-        return _logits(p, cfg, x)[:, 0], caches
+        lg = _logits(p, cfg, x)[:, 0]
+    return (lg, caches, stats) if with_stats else (lg, caches)
 
 
 def _per_group(f, stacked: jax.Array):

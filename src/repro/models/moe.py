@@ -27,7 +27,7 @@ from repro.core import ternary as tq
 from repro.core import twd
 from repro.models.ternary_linear import tlin_apply, tlin_compact
 
-__all__ = ["moe_init", "moe_apply", "export_moe", "decode_capacity"]
+__all__ = ["moe_init", "moe_apply", "export_moe", "serve_capacity"]
 
 
 def moe_init(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict:
@@ -99,52 +99,86 @@ def _expert_weights(p: dict, cfg: ModelConfig, x_dtype):
     return out
 
 
+def _act_quant(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """An expert linear's input as tlin_apply's QAT path takes a ternary
+    linear's: DAS, then the per-token int8 fake quant."""
+    if cfg.ternary.enabled and cfg.ternary.das is not None:
+        from repro.core import das as das_lib
+        m = das_lib.das_mask(x, block_size=cfg.ternary.das.block,
+                             keep=cfg.ternary.das.keep)
+        x = das_lib.das_apply(x, m)
+    if cfg.ternary.enabled:
+        x = tq.int8_fake_quant(x)
+    return x
+
+
 def _dispatch_compute(x_tok, weights, router, cfg: ModelConfig,
                       e_start, e_local: int, capacity: int):
     """Route (T, D) tokens, run experts [e_start, e_start+e_local), return
-    the partial combine (T, D) (zeros for tokens routed elsewhere)."""
+    the partial combine (T, D) (zeros for tokens routed elsewhere) and the
+    routing counters: the local experts the tokens reached and the largest
+    per-expert load.
+
+    When the capacity holds every token (serving: capacity = the call's
+    tokens), every local expert runs every token and the combine weighs its
+    output by the token's gate, 0 where the router did not pick it: the
+    dispatch's result without its sort, scatter and gathers, which at a
+    prefill's T * top_k rows do not fit the TPU's scoped VMEM."""
     e: MoeConfig = cfg.moe
     t, d = x_tok.shape
     wg, wi, wo = weights                                         # local stacks
-    logits = x_tok.astype(jnp.float32) @ router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                      # (T, E)
-    gate, expert = jax.lax.top_k(probs, e.top_k)                 # (T, K)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    with jax.named_scope("route"):
+        logits = x_tok.astype(jnp.float32) @ router.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                  # (T, E)
+        gate, expert = jax.lax.top_k(probs, e.top_k)             # (T, K)
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        # (T, K, E_local): which local expert each pick went to
+        pick = expert[..., None] == e_start + jnp.arange(e_local)
+        counts = jnp.sum(pick, axis=(0, 1))                      # (E_local,)
+    stats = {"experts_hit": jnp.sum(counts > 0).astype(jnp.int32),
+             "max_load": jnp.max(counts).astype(jnp.int32)}
 
-    flat_e = expert.reshape(-1)                                  # (T*K,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(flat_e, length=e.n_experts)
-    starts = jnp.cumsum(counts) - counts
-    pos_sorted = jnp.arange(flat_e.shape[0]) - starts[sorted_e]
-    pos = jnp.zeros_like(flat_e).at[order].set(pos_sorted)       # rank in expert
+    if capacity >= t:
+        with jax.named_scope("dispatch"):
+            x_in = _act_quant(x_tok, cfg)
+            g = jnp.sum(pick * gate[..., None], axis=1)          # (T, E_l)
+        with jax.named_scope("experts"):
+            h = jax.nn.silu(jnp.einsum("td,edf->etf", x_in, wg)) \
+                * jnp.einsum("td,edf->etf", x_in, wi)
+            y = jnp.einsum("etf,efd->etd", _act_quant(h, cfg), wo)
+        with jax.named_scope("combine"):
+            return jnp.einsum("te,etd->td", g.astype(y.dtype), y), stats
 
-    local_e = flat_e - e_start
-    ok = (local_e >= 0) & (local_e < e_local) & (pos < capacity)
-    slot = jnp.where(ok, local_e * capacity + pos, e_local * capacity)
+    with jax.named_scope("dispatch"):
+        flat_e = expert.reshape(-1)                              # (T*K,)
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        all_counts = jnp.bincount(flat_e, length=e.n_experts)
+        starts = jnp.cumsum(all_counts) - all_counts
+        pos_sorted = jnp.arange(flat_e.shape[0]) - starts[sorted_e]
+        pos = jnp.zeros_like(flat_e).at[order].set(pos_sorted)   # rank in expert
 
-    tok_idx = jnp.repeat(jnp.arange(t), e.top_k)
-    x_in = x_tok
-    if cfg.ternary.enabled and cfg.ternary.das is not None:
-        from repro.core import das as das_lib
-        m = das_lib.das_mask(x_in, block_size=cfg.ternary.das.block,
-                             keep=cfg.ternary.das.keep)
-        x_in = das_lib.das_apply(x_in, m)
-    if cfg.ternary.enabled:
-        x_in = tq.int8_fake_quant(x_in)
-    buf = jnp.zeros((e_local * capacity + 1, d), x_tok.dtype)
-    buf = buf.at[slot].set(x_in[tok_idx], mode="drop")
-    buf = buf[:-1].reshape(e_local, capacity, d)
+        local_e = flat_e - e_start
+        ok = (local_e >= 0) & (local_e < e_local) & (pos < capacity)
+        slot = jnp.where(ok, local_e * capacity + pos, e_local * capacity)
 
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, wg)) * jnp.einsum(
-        "ecd,edf->ecf", buf, wi)
-    y = jnp.einsum("ecf,efd->ecd", h, wo)                        # (E_l, C, D)
+        tok_idx = jnp.repeat(jnp.arange(t), e.top_k)
+        x_in = _act_quant(x_tok, cfg)
+        buf = jnp.zeros((e_local * capacity + 1, d), x_tok.dtype)
+        buf = buf.at[slot].set(x_in[tok_idx], mode="drop")
+        buf = buf[:-1].reshape(e_local, capacity, d)
 
-    y_flat = jnp.concatenate([y.reshape(e_local * capacity, d),
-                              jnp.zeros((1, d), y.dtype)], axis=0)
-    g = jnp.where(ok, gate.reshape(-1), 0.0).astype(y.dtype)
-    contrib = y_flat[jnp.minimum(slot, e_local * capacity)] * g[:, None]
-    return jnp.zeros((t, d), y.dtype).at[tok_idx].add(contrib)
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, wg)) * jnp.einsum(
+            "ecd,edf->ecf", buf, wi)
+        y = jnp.einsum("ecf,efd->ecd", _act_quant(h, cfg), wo)   # (E_l, C, D)
+
+    with jax.named_scope("combine"):
+        y_flat = jnp.concatenate([y.reshape(e_local * capacity, d),
+                                  jnp.zeros((1, d), y.dtype)], axis=0)
+        g = jnp.where(ok, gate.reshape(-1), 0.0).astype(y.dtype)
+        contrib = y_flat[jnp.minimum(slot, e_local * capacity)] * g[:, None]
+        return jnp.zeros((t, d), y.dtype).at[tok_idx].add(contrib), stats
 
 
 def _shared_ffn(p: dict, cfg: ModelConfig, x: jax.Array, kernel_mode: str):
@@ -164,40 +198,46 @@ def _ep_spec(sub: dict, ep_axis: str):
     return {k: P(ep_axis) for k in sub}
 
 
-def decode_capacity(cfg: ModelConfig, batch: int) -> int:
-    """No-drop per-expert capacity for a decode tick of ``batch`` tokens.
+def serve_capacity(cfg: ModelConfig, tokens: int) -> int:
+    """No-drop per-expert capacity for a serving call of ``tokens`` tokens
+    (a decode tick's batch, or a packed prefill's prompt prefix).
 
     The training-time capacity ``t * top_k / E * cf`` models a balanced
-    router over thousands of tokens; at decode t is the live batch (a
-    handful of rows), so a momentarily hot expert overflows the bound and
-    the overflow tokens are SILENTLY dropped from its mixture — making a
-    request's tokens depend on its batch-mates (batch-variant serving).
-    A single expert can receive at most one routed copy of each token, so
-    capacity == batch makes drops impossible at decode.
+    router over thousands of tokens; in serving, a momentarily hot expert
+    overflows the bound and the overflow tokens are SILENTLY dropped from
+    its mixture — making a request's tokens (and, in a prefill, its cached
+    K/V) depend on its batch- or pack-mates.  A single expert can receive at
+    most one routed copy of each token, so capacity == tokens makes drops
+    impossible.
     """
     del cfg
-    return max(1, batch)
+    return max(1, tokens)
 
 
 def moe_apply(p: dict, cfg: ModelConfig, x: jax.Array, *, mesh=None,
               dp_axes=("data",), ep_axis: str = "model",
-              kernel_mode: str = "ref", capacity: int | None = None
-              ) -> jax.Array:
+              kernel_mode: str = "ref", capacity: int | None = None,
+              with_stats: bool = False):
     """x: (B, S, D) -> (B, S, D).  EP via shard_map when a mesh is given.
 
-    ``capacity`` overrides the per-expert token capacity (serving decode
-    passes :func:`decode_capacity` so hot experts never drop tokens; None
-    keeps the capacity-factor formula used in training)."""
+    ``capacity`` overrides the per-expert token capacity (serving passes
+    :func:`serve_capacity` so hot experts never drop tokens; None keeps the
+    capacity-factor formula used in training).  ``with_stats`` (one device,
+    no mesh) also returns the routing counters of `_dispatch_compute`."""
     e: MoeConfig = cfg.moe
     b, s, d = x.shape
+    if with_stats and mesh is not None:
+        raise ValueError("MoE routing counters are read on one device only")
 
     if mesh is None:
         t = b * s
         cap = capacity if capacity is not None else max(
             1, min(t, int(t * e.top_k / e.n_experts * e.capacity_factor) + 1))
-        weights = _expert_weights(p, cfg, x.dtype)
-        y = _dispatch_compute(x.reshape(t, d), weights, p["router"], cfg,
-                              0, e.n_experts, cap).reshape(b, s, d)
+        with jax.named_scope("experts"):      # the unpack of every expert
+            weights = _expert_weights(p, cfg, x.dtype)
+        y, stats = _dispatch_compute(x.reshape(t, d), weights, p["router"],
+                                     cfg, 0, e.n_experts, cap)
+        y = y.reshape(b, s, d)
     else:
         ep = mesh.shape[ep_axis]
         dp = 1
@@ -219,8 +259,8 @@ def moe_apply(p: dict, cfg: ModelConfig, x: jax.Array, *, mesh=None,
             ei = jax.lax.axis_index(ep_axis)
             tl = x_blk.shape[0] * x_blk.shape[1]
             weights = _expert_weights(pe, cfg, x_blk.dtype)
-            y = _dispatch_compute(x_blk.reshape(tl, d), weights, router, cfg,
-                                  ei * e_local, e_local, cap)
+            y, _ = _dispatch_compute(x_blk.reshape(tl, d), weights, router,
+                                     cfg, ei * e_local, e_local, cap)
             return jax.lax.psum(y, ep_axis).reshape(x_blk.shape)
 
         y = jax.shard_map(
@@ -232,4 +272,4 @@ def moe_apply(p: dict, cfg: ModelConfig, x: jax.Array, *, mesh=None,
 
     if e.n_shared:
         y = y + _shared_ffn(p, cfg, x, kernel_mode)
-    return y
+    return (y, stats) if with_stats else y

@@ -78,17 +78,24 @@ def ffn_apply(p: dict, cfg: ModelConfig, x: jax.Array, *, kernel_mode="ref"):
 
 
 def _mixer_ffn(p: dict, cfg: ModelConfig, x: jax.Array, rt: Runtime,
-               decode: bool = False):
-    """The FFN/MoE half of an attention/gla block.  ``decode`` switches MoE
-    to the no-drop capacity (a hot expert must never drop a live request's
-    token mid-decode; see moe.decode_capacity)."""
+               serve: bool = False):
+    """The FFN/MoE half of an attention/gla block -> (y, the MoE routing
+    counters or None).  ``serve`` (prefill and decode) switches MoE to the
+    no-drop capacity (a hot expert must never drop a request's token; see
+    moe.serve_capacity)."""
     if cfg.moe is not None:
-        cap = (MOE.decode_capacity(cfg, x.shape[0] * x.shape[1])
-               if decode else None)
-        return MOE.moe_apply(p["moe"], cfg, x, mesh=rt.mesh,
-                             dp_axes=rt.dp_axes, ep_axis=rt.ep_axis,
-                             kernel_mode=rt.kernel_mode, capacity=cap)
-    return ffn_apply(p["ffn"], cfg, x, kernel_mode=rt.kernel_mode)
+        cap = (MOE.serve_capacity(cfg, x.shape[0] * x.shape[1])
+               if serve else None)
+        out = MOE.moe_apply(p["moe"], cfg, x, mesh=rt.mesh,
+                            dp_axes=rt.dp_axes, ep_axis=rt.ep_axis,
+                            kernel_mode=rt.kernel_mode, capacity=cap,
+                            with_stats=rt.mesh is None)
+        return out if rt.mesh is None else (out, None)
+    return ffn_apply(p["ffn"], cfg, x, kernel_mode=rt.kernel_mode), None
+
+
+def _ffn_scope(cfg: ModelConfig) -> str:
+    return "moe" if cfg.moe is not None else "ffn"
 
 
 # --------------------------------------------------------------------------
@@ -167,7 +174,7 @@ def block_train(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
         x = x + A.attn_train(_attn_params(bp, shared), cfg,
                              L.rmsnorm(bp["norm1"], x), kind,
                              serve_sparse=rt.serve_sparse, kernel_mode=km)
-        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
+        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)[0]
     elif kind == "mamba":
         y, _ = M.mamba_train(bp["mamba"], cfg, L.rmsnorm(bp["norm1"], x),
                              kernel_mode=km)
@@ -178,7 +185,7 @@ def block_train(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
         y, _ = G.gla_train(bp["gla"], cfg, L.rmsnorm(bp["norm1"], x),
                            kernel_mode=km)
         x = x + y
-        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
+        x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)[0]
     return x
 
 
@@ -200,11 +207,13 @@ def block_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
     """-> (x, cache_entry) with caches ready for decode at position L."""
     km = rt.kernel_mode
     if kind in ("attn", "local"):
-        with jax.named_scope("attn"):
+        sink, _ = A.kind_sink_window(cfg, kind, rt.serve_sparse)
+        with jax.named_scope("attn"), jax.named_scope(A.attn_scope(sink)):
             x, cache = _attn_prefill(bp, cfg, x, kind, shared, rt, batch,
                                      max_len)
-        with jax.named_scope("ffn"):
-            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
+        with jax.named_scope(_ffn_scope(cfg)):
+            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
+                               serve=True)[0]
         return x, cache
     if kind == "mamba":
         y, state = M.mamba_train(
@@ -217,8 +226,9 @@ def block_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
         y, s_fin = G.gla_train(bp["gla"], cfg, L.rmsnorm(bp["norm1"], x),
                                kernel_mode=km)
         x = x + y
-        with jax.named_scope("ffn"):
-            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt)
+        with jax.named_scope(_ffn_scope(cfg)):
+            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
+                               serve=True)[0]
         return x, {"s": s_fin}
     raise ValueError(kind)
 
@@ -239,7 +249,7 @@ def _attn_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
     else:                    # full attention -> full cache
         q, k, v = A.qkv_project(ap, cfg, xin, kernel_mode=km)
         pos = jnp.arange(x.shape[1])
-        rp = A._rope_fn(cfg)
+        rp = A._rope_fn(cfg, kind)
         q, k = rp(q, pos), rp(k, pos)
         o = A.flash_masked(q, k, v, pos, pos, sink=A.FULL_SINK, window=0,
                            softcap=cfg.attn_softcap)
@@ -258,27 +268,29 @@ def _attn_prefill(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
 def block_decode(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
                  cache, t, shared, rt: Runtime,
                  page_table: jax.Array | None = None):
-    """One-token decode; t is scalar (lock-step) or (B,) per-sequence
-    positions (continuous batching) — recurrent mixers are position-free.
-    ``page_table`` addresses paged attention caches (ignored by every other
-    layout)."""
+    """One-token decode -> (x, cache, MoE routing counters or None); t is
+    scalar (lock-step) or (B,) per-sequence positions (continuous batching)
+    — recurrent mixers are position-free.  ``page_table`` addresses paged
+    attention caches (ignored by every other layout)."""
     km = rt.kernel_mode
     if kind in ("attn", "local"):
-        with jax.named_scope("attn"):
+        sink, _ = A.kind_sink_window(cfg, kind, rt.serve_sparse)
+        with jax.named_scope("attn"), jax.named_scope(A.attn_scope(sink)):
             y, cache = A.attn_decode(_attn_params(bp, shared), cfg,
                                      L.rmsnorm(bp["norm1"], x), cache, t,
                                      kind, serve_sparse=rt.serve_sparse,
                                      kernel_mode=km, page_table=page_table)
             x = x + y
-        with jax.named_scope("ffn"):
-            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
-                               decode=True)
-        return x, cache
+        with jax.named_scope(_ffn_scope(cfg)):
+            y, st = _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
+                               serve=True)
+            x = x + y
+        return x, cache, st
     if kind == "mamba":
         y, cache = M.mamba_decode(bp["mamba"], cfg,
                                   L.rmsnorm(bp["norm1"], x), cache, t,
                                   kernel_mode=km)
-        return x + y, cache
+        return x + y, cache, None
     if kind == "rwkv":
         xt = L.rmsnorm(bp["norm1"], x)
         y_t, st = R.rwkv_time_mix_step(bp["rwkv"], cfg, xt, cache,
@@ -288,15 +300,16 @@ def block_decode(bp: dict, cfg: ModelConfig, x: jax.Array, kind: str,
         y_c, shift_c = R.rwkv_channel_mix_step(bp["rwkv"], cfg, xc,
                                                cache["shift_c"],
                                                kernel_mode=km)
-        return x1 + y_c, {**st, "shift_c": shift_c}
+        return x1 + y_c, {**st, "shift_c": shift_c}, None
     if kind == "gla":
         y, cache = G.gla_decode(bp["gla"], cfg, L.rmsnorm(bp["norm1"], x),
                                 cache, kernel_mode=km)
         x = x + y
-        with jax.named_scope("ffn"):
-            x = x + _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
-                               decode=True)
-        return x, cache
+        with jax.named_scope(_ffn_scope(cfg)):
+            y, st = _mixer_ffn(bp, cfg, L.rmsnorm(bp["norm2"], x), rt,
+                               serve=True)
+            x = x + y
+        return x, cache, st
     raise ValueError(kind)
 
 
@@ -390,26 +403,47 @@ def stack_decode(params: dict, cfg: ModelConfig, x: jax.Array, caches: dict,
     pat = cfg.layer_pattern
     shared = params["shared"]
 
-    new_stacked = None
+    new_stacked = stats_stacked = None
     if params["stacked"] is not None:
         def group(x, xs):
             gp, gc = xs
-            ncs = []
+            ncs, stats = [], []
             for j, kind in enumerate(pat):
                 # page_table is closure-captured: one shared (B, pages) table
                 # is loop-invariant across scan groups (each group's paged
                 # arena is a distinct leaf of gc)
-                x, nc = block_decode(gp[j], cfg, x, kind, gc[j], t, shared,
-                                     rt, page_table)
+                x, nc, st = block_decode(gp[j], cfg, x, kind, gc[j], t,
+                                         shared, rt, page_table)
                 ncs.append(nc)
-            return x, tuple(ncs)
-        x, new_stacked = jax.lax.scan(group, x,
-                                      (params["stacked"], caches["stacked"]))
-    new_tail = []
+                stats.append(st)
+            return x, (tuple(ncs), tuple(stats))
+        x, (new_stacked, stats_stacked) = jax.lax.scan(
+            group, x, (params["stacked"], caches["stacked"]))
+    new_tail, tail_stats = [], []
     start = cfg.n_layers - len(params["tail"])
     for i, bp in enumerate(params["tail"]):
         kind = cfg.layer_kinds()[start + i]
-        x, nc = block_decode(bp, cfg, x, kind, caches["tail"][i], t, shared,
-                             rt, page_table)
+        x, nc, st = block_decode(bp, cfg, x, kind, caches["tail"][i], t,
+                                 shared, rt, page_table)
         new_tail.append(nc)
-    return x, {"stacked": new_stacked, "tail": tuple(new_tail)}
+        tail_stats.append(st)
+    return (x, {"stacked": new_stacked, "tail": tuple(new_tail)},
+            _layer_counters(stats_stacked, tail_stats))
+
+
+def _layer_counters(stacked, tail) -> dict | None:
+    """The MoE layers' routing counters in layer order, {name: (n,) int32}
+    (None without MoE layers).  ``stacked`` holds one entry per pattern
+    position, each counter (groups,) from the scan; ``tail`` one per
+    unrolled layer."""
+    rows = []
+    if stacked is not None:
+        mine = [st for st in stacked if st is not None]
+        if mine:
+            rows.append({k: jnp.stack([st[k] for st in mine], 1).reshape(-1)
+                         for k in mine[0]})
+    rows += [{k: v[None] for k, v in st.items()} for st in tail
+             if st is not None]
+    if not rows:
+        return None
+    return {k: jnp.concatenate([r[k] for r in rows]) for k in rows[0]}

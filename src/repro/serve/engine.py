@@ -34,8 +34,8 @@ mamba (ssm carry + chunk-replay buffers), rwkv (wkv + shifts) and gla
 (state matrix).  Recurrent-only configs have ``_chunk = 1``: the whole
 prompt absorbs through batch-1 prefill (one compile per prompt length)
 and decode carries pure state — hybrid stacks mix both in one pytree.
-MoE configs decode with no-drop expert capacity (models/moe
-decode_capacity); ``ServeConfig.moe_expert_capacity`` optionally bounds
+MoE configs prefill and decode with no-drop expert capacity (models/moe
+serve_capacity); ``ServeConfig.moe_expert_capacity`` optionally bounds
 the per-expert tick load via admission control instead of token drops.
 
 ``ServeConfig(layout="paged")`` swaps the dense per-slot full caches for a
@@ -173,6 +173,11 @@ class EngineStats:
     moe_capacity_deferrals: int = 0  # admissions deferred by the MoE
                                      # expert-capacity bound (ticks a ready
                                      # request waited for a slot to retire)
+    # MoE routing (zero without MoE layers): read from the decode ticks
+    # (models/moe._dispatch_compute; serving capacity drops no token)
+    moe_experts_hit: int = 0      # sum over ticks and MoE layers of the
+                                  # experts a tick's tokens reached
+    moe_max_load: int = 0         # most tokens one expert took in a tick
     preemptions: int = 0          # over-budget slots truncated to rescue a
                                   # deadline-critical queued request
     # elastic recovery (zero unless a WorkerFailure was survived)
@@ -280,6 +285,8 @@ class ServeEngine:
         # thread); this lock covers the scheduler + result-dict handoffs
         self._lock = threading.RLock()
         self._uses_embeds = MD.uses_embeds(cfg)
+        # MoE configs also return the routing counters from the step
+        self.moe_tick: dict | None = None   # the last tick's, per MoE layer
         self._cache_dtype = jnp.dtype(cfg.dtype)
         kinds = cfg.layer_kinds()
         sw = [A.kind_sink_window(cfg, k, rt.serve_sparse) for k in kinds
@@ -534,16 +541,16 @@ class ServeEngine:
         if self._uses_embeds:
             x = jnp.take(sparams["embed"], tok, axis=0).astype(jnp.float32)
             x = jnp.where(forced[:, None], forced_x, x)[:, None, :]
-            logits, caches = MD.decode_step(sparams, self.cfg, caches, x, t,
-                                            self.rt, pt)
         else:
-            logits, caches = MD.decode_step(sparams, self.cfg, caches, tok, t,
-                                            self.rt, pt)
+            x = tok
+        logits, caches, *moe = MD.decode_step(
+            sparams, self.cfg, caches, x, t, self.rt, pt,
+            with_stats=self.cfg.moe is not None)
         with jax.named_scope("sample"):
             keys = jax.vmap(self._fold_key)(uids, counters)
             next_tok = self._sampler(logits, keys, temps)
             next_tok = jnp.where(active, next_tok, 0)
-        return next_tok, caches
+        return (next_tok, caches, *moe)
 
     def _insert_fn(self, big, small, slot):
         """Overwrite one slot's rows with a batch-1 cache pytree."""
@@ -1347,9 +1354,11 @@ class ServeEngine:
             with TraceAnnotation("serve.tick.prepare", step_num=step):
                 n_active, args = self._tick_inputs()
             with TraceAnnotation("serve.tick.dispatch", step_num=step):
-                next_tok, self.caches = self._step(self.sparams, self.caches,
-                                                   *args)
+                next_tok, self.caches, *moe = self._step(
+                    self.sparams, self.caches, *args)
             with TraceAnnotation("serve.tick.wait", step_num=step):
+                if moe:
+                    next_tok = self._read_moe(next_tok, moe[0])
                 next_tok = np.asarray(next_tok)
 
             self.stats.decode_steps += 1
@@ -1361,6 +1370,16 @@ class ServeEngine:
             if self.telemetry is not None:
                 self.telemetry.on_tick(self, n_active,
                                        time.perf_counter() - tick_t0)
+
+    def _read_moe(self, next_tok, tick: dict):
+        """Fetch a tick's tokens with its MoE routing counters (one
+        transfer); fold them into the stats.  -> the tokens, on the host."""
+        next_tok, tick = jax.device_get((next_tok, tick))
+        self.moe_tick = tick
+        st = self.stats
+        st.moe_experts_hit += int(tick["experts_hit"].sum())
+        st.moe_max_load = max(st.moe_max_load, int(tick["max_load"].max()))
+        return next_tok
 
     def _tick_inputs(self) -> tuple[int, tuple]:
         """The decode step's per-slot inputs, built on the host and

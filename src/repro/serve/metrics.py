@@ -14,7 +14,9 @@ From those it maintains (a) cumulative counters that must agree with
 ``EngineStats`` (tokens, requests, preemptions — test-asserted), (b) a
 rolling window of recent ticks/requests for live gauges (tok/s over wall
 time, slot utilization, TTFT/latency/queue-wait percentiles in virtual
-steps, TTFT and queue wait in wall seconds, SLO attainment), and (c) an
+steps, TTFT and queue wait in wall seconds, SLO attainment; for a MoE
+model the experts a tick reaches per MoE layer and the largest
+per-expert load of a tick), and (c) an
 optional JSON-lines export: one ``{"type": "request", ...}`` line per
 finished request plus a ``{"type": "tick", ...}`` snapshot line every
 ``snapshot_every`` ticks — the flight recorder
@@ -62,6 +64,9 @@ class Telemetry:
         self._ticks: deque = deque(maxlen=window)
         # rolling finished-request records (dicts, see on_finish)
         self._recent: deque = deque(maxlen=window)
+        # rolling MoE routing per tick: (mean experts hit over the MoE
+        # layers, largest per-expert load in any of them)
+        self._moe: deque = deque(maxlen=window)
         # cumulative counters (must track EngineStats)
         self.tokens_out = 0
         self.requests_finished = 0
@@ -102,10 +107,14 @@ class Telemetry:
             self.queue_wait_steps += vtime - req.arrival
 
     def on_tick(self, engine, n_active: int, wall_dt: float) -> None:
+        moe = engine.moe_tick
         with self._lock:
             delta = self._sync_tokens_locked(engine)
             self.ticks_seen += 1
             self._ticks.append((wall_dt, n_active, delta))
+            if moe is not None:
+                self._moe.append((float(np.mean(moe["experts_hit"])),
+                                  int(np.max(moe["max_load"]))))
             due = (self._f is not None
                    and self.ticks_seen % self._snapshot_every == 0)
         if due:
@@ -164,6 +173,7 @@ class Telemetry:
         with self._lock:
             ticks = list(self._ticks)
             recent = list(self._recent)
+            moe = list(self._moe)
             totals = {
                 "tokens_out": self.tokens_out,
                 "requests_finished": self.requests_finished,
@@ -204,6 +214,10 @@ class Telemetry:
             "slo_attainment": (totals["slo_met"] / totals["slo_tracked"]
                                if totals["slo_tracked"] else None),
         }
+        if moe:
+            out["moe"] = {
+                "experts_hit_per_layer": float(np.mean([m[0] for m in moe])),
+                "max_expert_load": max(m[1] for m in moe)}
         return out
 
     def snapshot(self, engine=None) -> dict:
@@ -226,6 +240,8 @@ class Telemetry:
                 "reshards": st.reshards,
                 "recovery_seconds": round(st.recovery_seconds, 6),
                 "kernel_fallbacks": engine.kernel_fallback_deltas(),
+                "moe_experts_hit": st.moe_experts_hit,
+                "moe_max_load": st.moe_max_load,
             }
             pool = engine.pool_stats()
             out["pool"] = {k: pool[k] for k in

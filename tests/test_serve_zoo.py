@@ -8,7 +8,7 @@ MoE is the sharpest case: the training-time expert capacity
 ``t * top_k / E * cf`` would let a momentarily hot expert drop whichever
 request happened to share the decode tick, so the configs here force a
 production-tight ``capacity_factor=1.0`` and rely on the engine's
-no-drop decode capacity (models/moe.decode_capacity).
+no-drop serving capacity (models/moe.serve_capacity).
 
 Same joint-vs-solo assertion style as test_serve_engine.py: replay a
 staggered-admission trace, then each request alone, and require exact
@@ -32,7 +32,8 @@ pytestmark = pytest.mark.slow
 FAMILIES = ["zamba2-2.7b",        # mamba/attn hybrid, shared attention
             "rwkv6-3b",           # pure rwkv recurrent
             "gla-1.3b",           # pure gla recurrent
-            "qwen3-moe-30b-a3b"]  # MoE FFN over LPSA attention
+            "qwen3-moe-30b-a3b",  # MoE FFN over LPSA attention
+            "mellum2-12b-a2.5b"]  # MoE FFN over sliding rings + full caches
 
 
 @functools.lru_cache(maxsize=None)

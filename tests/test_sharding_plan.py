@@ -24,6 +24,7 @@ ARCHS = sorted(ARCH_MODULES)
 REPLICATED_2D = {
     "gla-1.3b": {"layers/tail/[i]/gla/wa1"},
     "kimi-k2-1t-a32b": {"layers/tail/[i]/moe/router"},
+    "mellum2-12b-a2.5b": {"layers/tail/[i]/moe/router"},
     "qwen3-moe-30b-a3b": {"layers/tail/[i]/moe/router"},
     "rwkv6-3b": {
         "layers/tail/[i]/rwkv/cr/packed", "layers/tail/[i]/rwkv/mix_c",

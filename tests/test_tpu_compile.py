@@ -8,6 +8,11 @@ LPSA ring of 1024) for a *described* v5e chip — no chip is needed — and
 assert that the Pallas call survives into the compiled program, under the
 name a device trace gives it.
 
+The Mellum MoE configuration's whole decode step (4 slots) and one packed
+prefill compile the same way at its published widths: they must fit the
+chip's memory and keep the Pallas attention kernel for GQA 32:4 at head dim
+128 over both its sliding rings and its full caches.
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU compiler library at a time, so a module-level
 call would make the test workers collect different tests.
@@ -140,3 +145,96 @@ def test_das_compact_has_no_gather_or_sort(one_chip, m, k):
     opcodes = {o.group(1) for o in ops if o}
     assert "fusion" in opcodes or "reduce" in opcodes
     assert not opcodes & {"gather", "sort"}, sorted(opcodes)
+
+
+@pytest.fixture(scope="module")
+def mellum_programs(one_chip):
+    """Compiled decode step (4 slots, context 2048) and one-pack prefill of
+    the Mellum configuration at full width, from shapes alone."""
+    import functools
+    import types
+
+    from repro.configs import get_config
+    from repro.models import model as MD
+    from repro.models.transformer import Runtime
+    from repro.serve.engine import ServeEngine
+    from repro.serve.sampler import make_sampler
+
+    cfg, rt, slots, context = get_config("mellum2-12b-a2.5b"), \
+        Runtime(kernel_mode="pallas"), 4, 2048
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    sp = shaped(jax.eval_shape(
+        lambda k: MD.export_serving(MD.init_params(k, cfg), cfg),
+        jax.random.PRNGKey(0)))
+    caches = shaped(jax.eval_shape(lambda: MD.init_caches(
+        None, cfg, slots, context, rt, jnp.bfloat16)))
+    ns = types.SimpleNamespace(cfg=cfg, rt=rt, _uses_embeds=False,
+                               _base_key=jax.random.PRNGKey(0),
+                               _sampler=make_sampler(0))
+    ns._fold_key = functools.partial(ServeEngine._fold_key, ns)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (slots,), dtype,
+                                    sharding=one_chip)
+    step = jax.jit(functools.partial(ServeEngine._step_fn, ns),
+                   donate_argnums=(1,)).lower(
+        sp, caches, None, vec(jnp.int32), vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.int32), vec(jnp.int32), vec(bool), vec(bool),
+        vec(jnp.float32, slots, cfg.d_model)).compile()
+    prefill = jax.jit(lambda p, x: MD.prefill(
+        p, cfg, x, rt, max_len=context)).lower(
+        sp, vec(jnp.int32, 1, cfg.lpsa.chunk)).compile()
+    return {"step": step, "prefill": prefill}
+
+
+def test_mellum_step_runs_the_attention_kernel_on_both_kinds(
+        mellum_programs):
+    """The decode step of every layer of the scanned pattern (3 sliding
+    rings of 1024, 1 full cache of 2048) calls the Pallas attention kernel
+    with GQA's 4 kv heads, and the benchmark's trace reduction, which finds
+    the kernel by the operand types a trace event's name lists
+    (bench/lib/kernels.json, read by attn_roofline and moe_attn_roofline),
+    finds each call."""
+    import json
+    import os
+    import re
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "lib",
+                        "kernels.json")
+    pats = json.load(open(path))["ops"]["sparse_attention"]
+    ins = _instructions(mellum_programs["step"].as_text())
+    calls = [i for i in ins if 'custom_call_target="tpu_custom_call"' in i]
+    assert len(calls) == 4 and all(
+        i.startswith("%vmap_sparse_attn_.") for i in calls), calls
+    lens = []
+    for i in calls:
+        types = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}",
+                           i.split("operand_layout_constraints={", 1)[1])
+        # the event name as a trace lists it: each operand with its type
+        event = (i.split(" custom-call(", 1)[0] + " custom-call(" + ", ".join(
+            f"{t} %x{j}" for j, t in enumerate(types))
+            + '), custom_call_target="tpu_custom_call"')
+        assert any(re.search(p, event) for p in pats), event
+        lens.append(int(re.match(r"bf16\[4,4,(\d+),128\]", types[1])[1]))
+    assert types[0].startswith("bf16[4,32,1,128]")
+    assert sorted(lens) == [1024, 1024, 1024, 2048], lens
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_mellum_fits_one_chip(mellum_programs, program):
+    m = mellum_programs[program].memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert used < 10e9, used        # of the chip's 16 GB
+    assert m.argument_size_in_bytes > 3e9   # every expert is held
+
+
+def test_mellum_decode_keeps_the_attention_kernel(mellum_programs):
+    """Both kinds of attention (the 1024-slot sliding rings and the
+    2048-position full caches) decode through the Pallas kernel."""
+    calls = [i for i in _instructions(mellum_programs["step"].as_text())
+             if 'custom_call_target="tpu_custom_call"' in i]
+    assert len({re.search(r"bf16\[4,4,(\d+),128\]", c).group(1)
+                for c in calls}) == 2, calls

@@ -151,9 +151,10 @@ def test_executables_are_named(served, module):
 @pytest.mark.parametrize("program,scope", [
     ("step", "embed"), ("step", "attn"), ("step", "ffn"),
     ("step", "lm_head"), ("step", "sample"), ("step", "das"),
-    ("step", "twd_unpack"), ("step", "kv_write"),
+    ("step", "twd_unpack"), ("step", "kv_write"), ("step", "attn_window"),
     ("prefill", "embed"), ("prefill", "attn"), ("prefill", "ffn"),
     ("prefill", "lm_head"), ("prefill", "das"), ("prefill", "kv_write"),
+    ("prefill", "attn_window"),
 ])
 def test_compiled_programs_carry_the_scopes(hlo, program, scope):
     assert any(scope in op.split("/") for op in _op_names(hlo[program])), \
